@@ -39,6 +39,8 @@ import argparse
 import json
 import sys
 
+from repro.compile_cache import place_compile_cache
+
 
 def environment_fingerprint() -> dict:
     """What was measured *on*: the software stack and thread pinning that
@@ -83,6 +85,7 @@ def main(argv=None) -> None:
     ap.add_argument("--json", default="BENCH_pr10.json",
                     help="machine-readable output path ('' disables)")
     args = ap.parse_args(argv)
+    place_compile_cache()
 
     rows = []
 

@@ -38,7 +38,7 @@ fi
 # test file stopped importing or someone deleted coverage).  pytest also
 # exits non-zero on collection errors, so a broken import fails CI rather
 # than silently shrinking the suite.
-TIER1_BASELINE=394
+TIER1_BASELINE=472
 collected=$(python -m pytest --collect-only -q 2>/dev/null | tail -1 \
             | grep -o '[0-9]\+ tests collected' | grep -o '^[0-9]\+' || echo 0)
 if [ "${collected}" -lt "${TIER1_BASELINE}" ]; then

@@ -217,11 +217,11 @@ def _build_grouped_perquery() -> BuiltEntry:
           "cascade + O(k x shards) merge)",
           tags=("serve", "pruned", "sharded"))
 def _build_sharded_pruned() -> BuiltEntry:
-    import jax
     from repro.core import retrieval_head
+    from repro.launch.mesh import make_mesh
 
     params, cfg = _seqrec_setup()
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     params = {**params, "item_emb":
               retrieval_head.ensure_sharded_pruned_state(
                   params["item_emb"], mesh, k_hint=5)}
@@ -266,12 +266,12 @@ def _build_flat_hier() -> BuiltEntry:
           "ONE shard_map",
           tags=("serve", "pruned", "sharded", "hier"))
 def _build_sharded_hier() -> BuiltEntry:
-    import jax
     from repro.core import retrieval_head
+    from repro.launch.mesh import make_mesh
     from repro.models import seqrec as seqrec_lib
 
     params, cfg = _seqrec_setup()
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     params = {**params, "item_emb":
               retrieval_head.ensure_sharded_pruned_state(
                   params["item_emb"], mesh, k_hint=5, super_factor=4)}
@@ -459,7 +459,7 @@ def _engine_entry(*, grouped: bool, base_id: int) -> BuiltEntry:
             note="config-static group count"),)
 
     return BuiltEntry(
-        fn=lambda seqs: eng._serve_fn(seqs, k),
+        fn=lambda seqs: eng._serve_fn(seqs, k, eng._data),
         args=(sds,),
         static_specs=specs,
         dispatch_counter=lambda: _count_engine_dispatches(eng, cfg, k,
@@ -567,7 +567,7 @@ def _build_engine_mutable() -> BuiltEntry:
     k, max_batch = 5, 8
     eng = RetrievalEngine.for_seqrec_mutable(params, cfg, mstate, k=k,
                                              max_batch=max_batch)
-    assert eng._head_state is not None, "mutable engine must be swappable"
+    assert eng._swappable, "mutable engine must be swappable"
 
     sds = jax.ShapeDtypeStruct((4, cfg.max_seq_len), jnp.int32)
 
@@ -627,7 +627,7 @@ def _build_engine_mutable() -> BuiltEntry:
     )
 
     return BuiltEntry(
-        fn=lambda seqs: eng._serve_fn(seqs, k, eng._head_state),
+        fn=lambda seqs: eng._serve_fn(seqs, k, eng._data),
         args=(sds,),
         static_specs=specs,
         dispatch_counter=count,
@@ -741,7 +741,7 @@ def _build_router_replicated() -> BuiltEntry:
     )
 
     return BuiltEntry(
-        fn=lambda seqs: eng._serve_fn(seqs, k),
+        fn=lambda seqs: eng._serve_fn(seqs, k, eng._data),
         args=(sds,),
         static_specs=specs,
         dispatch_counter=count,
@@ -785,7 +785,7 @@ def _build_router_durable() -> BuiltEntry:
     eng = router.engines[0]
     assert all(e.ladder == eng.ladder for e in router.engines), (
         "replicas must share the lead engine's calibrated ladder")
-    assert eng._head_state is not None, "fleet must be hot-swappable"
+    assert eng._swappable, "fleet must be hot-swappable"
 
     sds = jax.ShapeDtypeStruct((4, cfg.max_seq_len), jnp.int32)
 
@@ -876,7 +876,7 @@ def _build_router_durable() -> BuiltEntry:
     )
 
     return BuiltEntry(
-        fn=lambda seqs: eng._serve_fn(seqs, k, eng._head_state),
+        fn=lambda seqs: eng._serve_fn(seqs, k, eng._data),
         args=(sds,),
         static_specs=specs,
         dispatch_counter=count,

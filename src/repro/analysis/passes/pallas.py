@@ -16,11 +16,15 @@ previously lived in ROADMAP's "validate on real TPU" list:
   ``/opt/skills/guides``' Pallas notes).
 * **tiling / divisibility** — each block's last dim must be the full
   array dim or a multiple of 128 (the lane width); the second-minor dim
-  must be the full array dim, 1 (degenerate, layout-free), or a multiple
-  of the dtype's min sublane tile — 8 for 4-byte types, 16 for 2-byte,
-  **32 for int8/uint8 codes**.  Misaligned int8 code blocks are exactly
-  the class of mistake that lowers fine in interpret mode and dies (or
-  silently pads) on real TPU hardware.
+  must be the full array dim or a multiple of the dtype's min sublane
+  tile — 8 for 4-byte types, 16 for 2-byte, **32 for int8/uint8 codes**.
+  A squeezed (``None``) dim counts as 1, as the TPU lowering counts it,
+  so a ``(TB, 1, K)`` or ``(TB, None, K)`` block on a ``(B, S, K)`` array
+  fails here exactly as it fails the chip's compiler.  Rank-1 blocks must
+  be the full array or a multiple of 128 x (4 / itemsize).  The TPU
+  lowering itself asks only 8 sublanes of every dtype; the 16/32 rows
+  for narrow dtypes are this pass's stricter rule, the native packed
+  tile, below which Mosaic relayouts (pads) the block.
 * **sentinel clamp** — the compacted-tile kernels drive the codes block
   index from a scalar-prefetched slot table padded with ``-1`` sentinels;
   the contract is that index maps clamp ``-1`` to block 0 (the kernel
@@ -49,12 +53,17 @@ DEFAULT_VMEM_BUDGET = 8 * 1024 * 1024
 MAX_GRID_POINTS = 64
 
 
+def _block_dims(bm) -> Tuple[int, ...]:
+    """The block's size in every array dim; a squeezed dim is 1."""
+    return tuple(d if isinstance(d, int) else getattr(d, "block_size", 1)
+                 for d in bm.block_shape)
+
+
 def _block_nbytes(bm) -> int:
-    shape = [d for d in bm.block_shape if isinstance(d, int)]
     n = 1
-    for d in shape:
+    for d in _block_dims(bm):
         n *= d
-    return n * bm.array_shape_dtype.dtype.itemsize
+    return n * bm.array_aval.dtype.itemsize
 
 
 def _grid_points(grid) -> List[Tuple[int, ...]]:
@@ -151,24 +160,31 @@ class PallasContractPass(AnalysisPass):
                     f"(2x sum of block buffers) exceeds the "
                     f"{budget}-byte budget",
                     details={"vmem_bytes": vmem, "budget": budget,
-                             "blocks": [list(bm.block_shape)
+                             "blocks": [list(_block_dims(bm))
                                         for bm in gm.block_mappings]}))
 
             # -- tiling / divisibility --------------------------------------
             for bi, bm in enumerate(gm.block_mappings):
-                block = [d for d in bm.block_shape if isinstance(d, int)]
-                arr = bm.array_shape_dtype.shape
-                itemsize = bm.array_shape_dtype.dtype.itemsize
-                if len(block) < 2 or len(block) != len(arr):
-                    continue   # scalars / squeezed blocks: layout-free
+                block = _block_dims(bm)
+                arr = bm.array_aval.shape
+                itemsize = bm.array_aval.dtype.itemsize
+                if not block:
+                    continue   # scalar operand: no layout
                 sub_min = SUBLANE_MIN.get(itemsize, 8)
                 last, arr_last = block[-1], arr[-1]
-                sub, arr_sub = block[-2], arr[-2]
                 bad = []
-                if last != arr_last and last % LANE != 0:
+                if len(block) == 1:
+                    lane = LANE * max(1, 4 // itemsize)
+                    if last != arr_last and last % lane != 0:
+                        bad.append(f"rank-1 block {last} (array "
+                                   f"{arr_last}): not full and not a "
+                                   f"multiple of {lane}")
+                elif last != arr_last and last % LANE != 0:
                     bad.append(f"lane dim {last} (array {arr_last}): not "
                                f"full and not a multiple of {LANE}")
-                if sub != arr_sub and sub != 1 and sub % sub_min != 0:
+                sub, arr_sub = ((block[-2], arr[-2]) if len(block) > 1
+                                else (arr_last, arr_last))
+                if sub != arr_sub and sub % sub_min != 0:
                     bad.append(f"sublane dim {sub} (array {arr_sub}): not "
                                f"full and not a multiple of {sub_min} for "
                                f"itemsize {itemsize}")
@@ -176,23 +192,20 @@ class PallasContractPass(AnalysisPass):
                     findings.append(Finding(
                         self.name, entrypoint, SEV_ERROR, "tiling",
                         f"{where} block#{bi} "
-                        f"{tuple(bm.block_shape)} on "
-                        f"{bm.array_shape_dtype.dtype} array {tuple(arr)}: "
+                        f"{tuple(block)} on "
+                        f"{bm.array_aval.dtype} array {tuple(arr)}: "
                         + "; ".join(bad),
-                        details={"block": list(bm.block_shape),
+                        details={"block": list(block),
                                  "array": list(arr),
-                                 "dtype": str(bm.array_shape_dtype.dtype),
+                                 "dtype": str(bm.array_aval.dtype),
                                  "violations": bad}))
 
             # -- sentinel clamp ---------------------------------------------
             if getattr(gm, "num_index_operands", 0):
                 pts = _grid_points(gm.grid)
                 for bi, bm in enumerate(gm.block_mappings):
-                    block = [d for d in bm.block_shape if isinstance(d, int)]
-                    arr = bm.array_shape_dtype.shape
                     nblocks = [max(1, -(-a // b)) for a, b in
-                               zip(arr, block)] if len(block) == len(arr) \
-                        else None
+                               zip(bm.array_aval.shape, _block_dims(bm))]
                     for pt in pts:
                         try:
                             neg = _eval_index_map(bm.index_map_jaxpr, pt, -1)
@@ -206,9 +219,8 @@ class PallasContractPass(AnalysisPass):
                                 f"({type(e).__name__}: {e})",
                                 details={"grid_point": list(pt)}))
                             break
-                        oob = (nblocks is not None and
-                               any(not 0 <= x < nb
-                                   for x, nb in zip(neg, nblocks)))
+                        oob = any(not 0 <= x < nb
+                                  for x, nb in zip(neg, nblocks))
                         if neg != zero or oob:
                             findings.append(Finding(
                                 self.name, entrypoint, SEV_ERROR,

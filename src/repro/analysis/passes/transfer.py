@@ -6,7 +6,7 @@ D2H is zero-copy and unguarded, which is exactly where CI runs.  This
 pass is the static complement:
 
 * **callback primitives** — ``pure_callback`` / ``io_callback`` /
-  ``debug_callback`` (and the legacy host_callback spellings) anywhere in
+  ``debug_callback`` / ``debug_print`` anywhere in
   the nested jaxpr mean the compiled computation re-enters Python per
   dispatch: a synchronisation point and a TPU-incompatibility on the
   serve path.  Flagged wherever they hide (cond branches, scan bodies,
@@ -28,8 +28,8 @@ from repro.analysis.core import (AnalysisPass, EntryContext, Finding,
                                  SEV_ERROR, iter_eqns)
 
 CALLBACK_PRIMITIVES = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
-    "outside_call", "host_callback_call",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback",
 })
 
 #: raw-numpy consts smaller than this ride the trace for free (scalars,
@@ -69,6 +69,7 @@ class HostTransferPass(AnalysisPass):
 
         n_big_consts = 0
         for c in jaxpr.consts:
+            c = getattr(c, "val", c)   # traced host consts wrap a numpy array
             if isinstance(c, np.ndarray) and c.nbytes > self.const_bytes_limit:
                 n_big_consts += 1
                 findings.append(Finding(

@@ -1,108 +1,44 @@
-"""JAX version-compatibility layer — the single import point for symbols
-that drifted across JAX releases.
+"""JAX API layer — the single import point for symbols whose spelling
+has moved between JAX releases.
 
-Policy
-------
-Library code in ``repro`` must not reach into ``jax.experimental`` (or probe
-``jax`` top-level attributes) for any symbol whose home has moved between
-JAX releases.  Each such symbol is resolved exactly once, here, at import
-time, and re-exported under a stable name:
+Library code in ``repro`` does not reach into ``jax.experimental`` for
+these symbols; it imports them from here, so the next API move is a
+one-file change.  Only the installed JAX is supported: no branch here
+serves a release that is not installed.
 
-* ``shard_map``    — ``jax.shard_map`` (new) falling back to
-  ``jax.experimental.shard_map.shard_map`` (old).  The wrapper accepts the
-  *new* keyword surface (``check_vma``, ``axis_names``) and translates to
-  the legacy one (``check_rep``, ``auto``) when running on an old JAX.
-* ``ANY`` / ``VMEM`` / ``SMEM`` — Pallas TPU memory-space symbols.  New
-  releases expose ``pltpu.MemorySpace``; older ones ``pltpu.TPUMemorySpace``
-  (same enum values, different name).
+* ``shard_map``    — ``jax.shard_map`` with ``axis_names`` accepted as any
+  iterable of mesh axes.
+* ``axis_size``    — ``jax.lax.axis_size``.
+* ``ANY`` / ``VMEM`` / ``SMEM`` — Pallas TPU memory spaces.
+* ``prefetch_scalar_grid_spec`` — ``pltpu.PrefetchScalarGridSpec``.
 * ``on_tpu()``     — backend probe shared by the kernel wrappers to pick
-  interpret mode on CPU containers.
-
-Adding a shim: resolve the newest spelling first, fall back to older ones,
-and keep the exported surface matching the *newest* JAX API so that call
-sites never degrade and the fallback branch is the one that eventually
-rots away.  Never version-sniff with ``jax.__version__`` — probe for the
-symbol itself.
+  interpret mode on CPU hosts.
 """
 from __future__ import annotations
 
 from typing import Any, Optional
 
 import jax
-
-# ---------------------------------------------------------------------------
-# shard_map: jax.shard_map (>= 0.4.35ish top-level export, keyword surface
-# check_vma/axis_names) vs jax.experimental.shard_map.shard_map
-# (check_rep/auto).
-# ---------------------------------------------------------------------------
-
-_NEW_SHARD_MAP = getattr(jax, "shard_map", None)
-if _NEW_SHARD_MAP is None:
-    from jax.experimental.shard_map import shard_map as _LEGACY_SHARD_MAP
-else:
-    _LEGACY_SHARD_MAP = None
+from jax.experimental.pallas import tpu as _pltpu
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
               axis_names: Optional[Any] = None):
-    """``jax.shard_map`` with a version-stable keyword surface.
-
-    ``check_vma`` maps to the legacy ``check_rep``; ``axis_names`` (the set
-    of mesh axes the body is Manual over — all axes when ``None``) maps to
-    the legacy complement argument ``auto``.
-    """
-    if _NEW_SHARD_MAP is not None:
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return _NEW_SHARD_MAP(f, **kwargs)
-    kwargs = dict(check_rep=check_vma)
+    """``jax.shard_map``; ``axis_names`` (the mesh axes the body is Manual
+    over — all axes when ``None``) may be any iterable."""
+    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                  check_vma=check_vma)
     if axis_names is not None:
-        kwargs["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _LEGACY_SHARD_MAP(f, mesh, in_specs, out_specs, **kwargs)
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kwargs)
 
 
-# ---------------------------------------------------------------------------
-# axis_size: jax.lax.axis_size (new) vs psum(1, axis) (works everywhere but
-# costs a trivial collective on old JAX; new JAX reads the mesh statically).
-# ---------------------------------------------------------------------------
+#: Size of a mapped mesh axis, inside a Manual region.
+axis_size = jax.lax.axis_size
 
-def axis_size(axis_name) -> Any:
-    """Size of a mapped mesh axis, inside a Manual region."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-# ---------------------------------------------------------------------------
-# Pallas TPU memory spaces: pltpu.MemorySpace (new) vs pltpu.TPUMemorySpace.
-# Import lazily-ish but resolve eagerly: pallas is always present in this
-# container; guard anyway so non-kernel code can import repro.compat on a
-# jax build without pallas extras.
-# ---------------------------------------------------------------------------
-
-try:
-    from jax.experimental.pallas import tpu as _pltpu
-except ImportError:                                    # pragma: no cover
-    _pltpu = None
-
-if _pltpu is not None:
-    _MEMORY_SPACE = getattr(_pltpu, "MemorySpace",
-                            getattr(_pltpu, "TPUMemorySpace", None))
-    ANY = _MEMORY_SPACE.ANY
-    VMEM = _MEMORY_SPACE.VMEM
-    SMEM = _MEMORY_SPACE.SMEM
-else:                                                  # pragma: no cover
-    _MEMORY_SPACE = ANY = VMEM = SMEM = None
-
-
-def tpu_memory_space():
-    """The Pallas TPU memory-space enum under whichever name this JAX has."""
-    if _MEMORY_SPACE is None:                          # pragma: no cover
-        raise ImportError("jax.experimental.pallas.tpu is unavailable")
-    return _MEMORY_SPACE
+ANY = _pltpu.MemorySpace.ANY
+VMEM = _pltpu.MemorySpace.VMEM
+SMEM = _pltpu.MemorySpace.SMEM
 
 
 def prefetch_scalar_grid_spec(*, num_scalar_prefetch, grid, in_specs,
@@ -110,21 +46,14 @@ def prefetch_scalar_grid_spec(*, num_scalar_prefetch, grid, in_specs,
     """``pltpu.PrefetchScalarGridSpec`` — the TPU grid spec whose scalar
     operands are available to BlockSpec index maps (the mechanism behind
     the compacted tile-index grid of the pruned retrieval route)."""
-    if _pltpu is None:                                 # pragma: no cover
-        raise ImportError("jax.experimental.pallas.tpu is unavailable")
     return _pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_scalar_prefetch, grid=grid,
         in_specs=in_specs, out_specs=out_specs)
 
 
-# ---------------------------------------------------------------------------
-# Backend probe shared by the kernel wrappers.
-# ---------------------------------------------------------------------------
-
 def on_tpu() -> bool:
     """True when the default JAX backend is a TPU (kernels compile);
-    False on CPU/GPU containers (kernels run in interpret mode)."""
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
+    False on CPU/GPU hosts (kernels run in interpret mode).  A backend
+    that fails to initialise raises here: a broken chip must not be
+    served as a silent CPU fallback."""
+    return jax.devices()[0].platform == "tpu"

@@ -16,7 +16,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import PQConfig
 from repro.core import pq as pq_lib
@@ -347,12 +347,58 @@ def ensure_sharded_pruned_state(params: Params, mesh, axis: str = "model", *,
             and st.backend == backend and st.super_factor == super_factor):
         return params
     b = params["sub_emb"].shape[1]
-    need = min(max(tile, k_local), n_local)
+    need = _shard_tile(tile, k_local, n_local)
     new = pruning.build_pruned_state(codes, b, need, shards=n_shards,
                                      backend=backend)
     if super_factor:
         new = pruning.with_super(new, super_factor)
     return {**params, "pruned": new}
+
+
+def _shard_tile(tile: int, k_local: int, n_local: int) -> int:
+    """Pruning tile of the item-sharded route: ``tile``, grown to hold the
+    per-shard top-``k_local`` when that is larger — and then to a multiple
+    of 128, the row count the TPU kernel's codes block needs (k=2048 on 4
+    shards asks for 2049) — and never past the shard's ``n_local`` rows."""
+    if k_local > tile:
+        tile = -(-k_local // 128) * 128
+    return min(tile, n_local)
+
+
+def _true_rows(params: Params, n_shards: int) -> int:
+    """Catalogue size of an item head: the codes' rows, unless
+    :func:`place_sharded` padded them to its shard-aligned state's
+    ``shards * n_local`` rows, in which case the state holds the size."""
+    rows = params["codes"].shape[0]
+    st = _pruned_state(params)
+    if (st is not None and st.shards == n_shards
+            and rows == st.shards * st.n_local):
+        return st.n_items
+    return rows
+
+
+def place_sharded(params: Params, mesh, axis: str = "model") -> Params:
+    """Place an item head on ``mesh`` as the item-sharded cascade reads it:
+    the codes padded to the shard-aligned state's ``shards * n_local``
+    rows and split over ``axis`` (each device holds its own N/shards
+    rows), the state's tile-major metadata split along its tile axis, and
+    every other leaf replicated.  The padded rows are never served: the
+    route takes the catalogue size from the state (:func:`_true_rows`).
+    Call after :func:`ensure_sharded_pruned_state`."""
+    st = _pruned_state(params)
+    if st is None or st.shards != mesh.shape[axis]:
+        raise ValueError("place_sharded needs the mesh-aligned pruned state "
+                         "of ensure_sharded_pruned_state")
+    codes = params["codes"]
+    codes = jnp.pad(codes, ((0, st.shards * st.n_local - codes.shape[0]),
+                            (0, 0)))
+    split = NamedSharding(mesh, P(axis))
+    out = jax.device_put({k: v for k, v in params.items()
+                          if k not in ("codes", "pruned")},
+                         NamedSharding(mesh, P()))
+    out["codes"] = jax.device_put(codes, split)
+    out["pruned"] = jax.device_put(st, split)
+    return out
 
 
 def top_items_pruned_sharded(params: Params, phi: jax.Array, k: int, mesh,
@@ -411,8 +457,8 @@ def top_items_pruned_sharded(params: Params, phi: jax.Array, k: int, mesh,
     from repro.kernels.pqtopk import ops as kernel_ops
     codes, sub_emb = params["codes"], params["sub_emb"]
     live = params.get("live")
-    n = codes.shape[0]
     n_shards = mesh.shape[axis]
+    n = _true_rows(params, n_shards)
     pad = (-n) % n_shards
     n_local = (n + pad) // n_shards
     # The local pass oversamples the top-(k + pad) so shard-padding rows
@@ -429,14 +475,15 @@ def top_items_pruned_sharded(params: Params, phi: jax.Array, k: int, mesh,
     if (state is None or state.shards != n_shards or state.tile < k_local
             or state.backend != want_backend):
         state = pruning.build_pruned_state(
-            codes, b, min(max(tile, k_local), n_local), shards=n_shards,
+            codes[:n], b, _shard_tile(tile, k_local, n_local),
+            shards=n_shards,
             backend=want_backend)
         if want_super > 1:
             state = pruning.with_super(state, want_super)
     hier = state.has_super
     tile = state.tile
     t_local = state.tiles_per_shard
-    codes_p = jnp.pad(codes, ((0, pad), (0, 0))) if pad else codes
+    codes_p = jnp.pad(codes, ((0, n + pad - codes.shape[0]), (0, 0)))
     if use_kernel is None:
         from repro import compat
         use_kernel = compat.on_tpu()
@@ -648,7 +695,7 @@ def top_items_pruned_sharded(params: Params, phi: jax.Array, k: int, mesh,
         # Tombstone mask rides the mesh axis alongside the codes (shard
         # padding rows are dead); everything else is the same ONE
         # shard_map — churn is pure data, so zero recompiles per swap.
-        live_p = jnp.pad(live, (0, pad)) if pad else live
+        live_p = jnp.pad(live, (0, n + pad - live.shape[0]))
 
         def body_live(codes_local, meta_local, live_local, sub_emb_, phi_):
             return shard_body(codes_local, meta_local, sub_emb_, phi_,

@@ -33,17 +33,26 @@ def tree_sum(parts):
     This is THE accumulation order for per-split partial scores: Algorithm 1
     (:func:`score_pqtopk`), the jnp kernel oracle (``kernels/pqtopk/ref.py``)
     and the Pallas tile kernel (``kernels/pqtopk/kernel.py``) all reduce
-    through this function, so their f32 rounding is bit-identical — parity
-    tests compare them at atol=0.  Also avoids materialising a (B, m, N)
-    stack and keeps the adds parallelisable (no loop-carried accumulator).
+    through it, so their f32 rounding is bit-identical — parity tests
+    compare them at atol=0.  Also avoids materialising a (B, m, N) stack
+    and keeps the adds parallelisable (no loop-carried accumulator).
     """
     parts = list(parts)
-    while len(parts) > 1:
-        nxt = [parts[i] + parts[i + 1] for i in range(0, len(parts) - 1, 2)]
-        if len(parts) % 2:
-            nxt.append(parts[-1])
-        parts = nxt
-    return parts[0]
+    return tree_sum_lazy(parts.__getitem__, len(parts))
+
+
+def tree_sum_lazy(make, n: int):
+    """``tree_sum([make(i) for i in range(n)])``, each part made only when
+    its subtree is reduced: the same association — pairs, then pairs of
+    pairs, the odd part carried up a level — so the same rounding, with
+    at most log2(n) + 1 partial sums alive at once (what a Pallas kernel's
+    VMEM holds).  The first ``p`` parts, ``p`` the largest power of two
+    below ``n``, form one complete subtree; the rest form the other."""
+    if n == 1:
+        return make(0)
+    p = 1 << ((n - 1).bit_length() - 1)
+    left = tree_sum_lazy(make, p)
+    return left + tree_sum_lazy(lambda i: make(p + i), n - p)
 
 
 def score_dense(w: jax.Array, phi: jax.Array) -> jax.Array:

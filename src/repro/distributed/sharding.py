@@ -17,7 +17,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro import compat
 
@@ -29,11 +29,25 @@ _PLAN: contextvars.ContextVar[Optional["ShardingPlan"]] = \
     contextvars.ContextVar("activation_plan", default=None)
 
 
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis ``Auto`` (the compiler propagates
+    shardings; ``with_sharding_constraint`` hints them).  ``jax.make_mesh``
+    defaults to ``Explicit`` axes since jax 0.9, which reject such hints
+    and make every ambiguous gather a type error."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 class ShardingPlan:
-    """Named activation specs bound to a mesh."""
+    """Named activation specs bound to a mesh.
+
+    The specs are hints to the compiler's sharding propagation, so the
+    plan keeps an all-``Auto`` view of the mesh it is given."""
 
     def __init__(self, mesh: Mesh, specs: Dict[str, P]):
-        self.mesh = mesh
+        self.mesh = auto_axes(mesh)
         self.specs = dict(specs)
 
     def sharding(self, name: str) -> Optional[NamedSharding]:

@@ -8,20 +8,14 @@ Two kernels:
   for int8/uint8 codes (4 for int32) vs 2*d bytes/item for dense scoring.
 
 * ``pq_topk_fused_kernel`` — additionally reduces each (batch tile × item
-  tile) block to its local top-K so only (B, n_slots, K) candidates ever
-  reach HBM; the final merge over tile winners happens outside.  Rebuilt
-  for PR 2 around three hardware-level wins:
+  tile) block to its local top-K so only (n_slots, B, K) candidates ever
+  reach HBM; the final merge over tile winners happens outside.
 
   1. **Batch tiling** — grid is (tile slot, batch tile), so B is unbounded:
      each step sees a (TB, m, b) slice of S instead of the whole batch.
-  2. **Single-pass top-k** — the old K-pass iterative max-extract re-scanned
-     the whole VMEM tile K times.  Now a two-phase reduction: one pass
-     computes per-block partial top-q over C = k-oversampled blocks
-     (``approx_topk``'s block-max structure, made exact by keeping
-     q = min(k, TN/C) per block — every global winner is a within-block
-     winner under the same value-then-index order), then an in-VMEM rerank
-     merges the C*q candidates.  Data is touched once; the rerank works on
-     the reduced candidate set.
+  2. **In-VMEM top-k** — K max-extract passes over the (TB, TN) score tile
+     (``_tile_topk``), built only from operations Mosaic lowers, with the
+     value-then-lowest-index order of ``lax.top_k``.
   3. **Compacted tile indices** — the item-tile axis is indirected through a
      scalar-prefetched index array (``PrefetchScalarGridSpec``), so the
      pruned retrieval route can run the same kernel over only the tiles
@@ -45,10 +39,11 @@ codes tile is fetched once; 2D: grid = (n_batch_tiles, n_slots), slots
 innermost so each group's S block is fetched once):
   tile_idx (n_slots,) i32     -> scalar prefetch (SMEM)
            or (n_batch_tiles, n_slots) i32
-  codes (N, m) i8/u8/i32      -> block (TN, m)       @ row tile_idx[...]
-  s     (B, m, b) f32         -> block (TB, m, b)    @ batch tile j
-  out_v (B, n_slots, K) f32   -> block (TB, 1, K)    @ (j, i)
-  out_i (B, n_slots, K) i32   -> block (TB, 1, K)    @ (j, i)
+  codes (N, m) i8/u8/u16/i32  -> block (TN, m)          @ row tile_idx[...]
+  s     (B, m, b) f32         -> block (TB, m, b)       @ batch tile j
+  live  (N/TN, 1, TN) i8      -> block (None, 1, TN)    @ row tile_idx[...]
+  out_v (n_slots, B, K) f32   -> block (None, TB, K)    @ (i, j)
+  out_i (n_slots, B, K) i32   -> block (None, TB, K)    @ (i, j)
 
 VMEM working set per step (TN=2048, b=256, TB=128, f32):
   onehot 2048*256*4 = 2 MiB, scores TB*TN*4 = 1 MiB, S m*b*TB*4 <= 1 MiB.
@@ -64,86 +59,107 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro import compat
-from repro.core.scoring import tree_sum
+from repro.core.scoring import tree_sum_lazy
 
 DEFAULT_TILE = 2048
 DEFAULT_BATCH_TILE = 128
-DEFAULT_OVERSAMPLE = 2
 NEG_INF = float("-inf")
+
+
+def _bf16_split(x):
+    """f32 -> (hi, mid, lo) bf16 with ``hi + mid + lo == x`` exactly: each
+    piece holds the next 8 significant bits of f32's 24, and each
+    remainder is exact in f32."""
+    hi = x.astype(jnp.bfloat16)
+    r = x - hi.astype(jnp.float32)
+    mid = r.astype(jnp.bfloat16)
+    lo = (r - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
 
 
 def _tile_scores(codes_ref, s_ref):
     """Shared body: one-hot MXU scoring of one item tile. -> (TB, TN) f32.
 
-    ``codes_ref`` may be int8/uint8 (b <= 128 / 256) or int32; the widen to
-    int32 happens in VMEM, so the 8-bit dtypes cut HBM code traffic 4x.
+    ``codes_ref`` may be int8/uint8 (b <= 128 / 256), uint16 or int32; the
+    widen to int32 happens in VMEM, so narrow dtypes cut HBM code traffic.
+
+    Exact in f32: the one-hot matrix is exact in bf16, and S goes through
+    the MXU as three bf16 pieces (:func:`_bf16_split`), so each product
+    selects one piece exactly and ``(hi + mid) + lo`` rebuilds the f32
+    entry of S bit for bit.  A single f32 matmul at the default precision
+    rounds S to bf16 (measured on a v5e: scores off by up to 2.6e-2);
+    ``Precision.HIGHEST`` takes six passes and was still off by an ulp.
     """
     codes = codes_ref[...].astype(jnp.int32)          # (TN, m)
     s = s_ref[...].astype(jnp.float32)                # (TB, m, b)
     tn, m = codes.shape
     b = s.shape[-1]
     iota = jax.lax.broadcasted_iota(jnp.int32, (tn, b), 1)
-    parts = []
-    for k in range(m):                                # m static -> unrolled
-        onehot = (codes[:, k][:, None] == iota).astype(jnp.float32)  # (TN, b)
-        parts.append(jax.lax.dot_general(
-            s[:, k, :], onehot,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ))                                            # (TB, TN)
-    # Each one-hot matmul is exact in f32 (a single nonzero per row), so the
-    # only rounding happens in the cross-split reduction — tree_sum keeps it
-    # bit-identical to score_pqtopk / the jnp oracle (see scoring.tree_sum).
-    return tree_sum(parts)
+
+    def split_scores(k):                              # -> (TB, TN)
+        onehot = (codes[:, k:k + 1] == iota).astype(jnp.bfloat16)  # (TN, b)
+        hi, mid, lo = (jax.lax.dot_general(
+            p, onehot, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+            for p in _bf16_split(s[:, k, :]))
+        return (hi + mid) + lo
+
+    # Each split's scores are exact, so the only rounding happens in the
+    # cross-split reduction — tree_sum's order keeps it bit-identical to
+    # score_pqtopk / the jnp oracle; the lazy form keeps few (TB, TN)
+    # partial sums in VMEM at once.
+    return tree_sum_lazy(split_scores, m)
 
 
 def pq_scores_kernel(codes_ref, s_ref, out_ref):
     out_ref[...] = _tile_scores(codes_ref, s_ref)
 
 
-def pick_blocks(tn: int, k: int, oversample: int = DEFAULT_OVERSAMPLE) -> int:
-    """Number of reduction blocks C for the two-phase tile top-k.
-
-    k-oversampled (C >= k*oversample) so the per-block depth q = min(k, TN/C)
-    stays shallow, capped at 128 (one lane register) and clamped to divide
-    TN (TN is always a multiple of 128 after wrapper rounding, so any
-    power-of-two C <= 128 divides it; tiny tiles fall back to C = TN).
-    """
-    c = 1
-    while c < max(1, k) * oversample:
-        c *= 2
-    c = min(c, 128)
-    while tn % c:
-        c //= 2
-    return max(c, 1)
+def _order_key(x):
+    """f32 -> i32 key with the total order ``lax.top_k`` sorts by (-0.0
+    below +0.0).  The map is its own inverse on the int32 bits."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return bits ^ ((bits >> 31) & 0x7FFFFFFF)
 
 
-def _tile_topk(scores, k: int, blocks: int):
-    """Exact top-k of one VMEM-resident score tile, single data pass.
+def _tile_topk(scores, k: int):
+    """Exact top-k of one VMEM-resident (TB, TN) score tile, k max-extract
+    passes in (value descending, column ascending) order — what
+    ``lax.top_k`` returns, ties included.
 
-    Phase 1: per-block partial top-q (q = min(k, W)) over C contiguous
-    blocks of width W = TN/C — the only pass over the (TB, TN) data.
-    Phase 2: rerank the (TB, C*q) candidates.  Exact: any global top-k
-    element ranks above < k items globally, hence above < k items within
-    its own block (same value-then-lowest-index order), hence appears among
-    its block's top-q.  Candidate order (block-major, rank-minor) preserves
-    ascending-column order among equal values, so ties break identically to
-    ``lax.top_k`` over the full tile.
+    Pass j keeps only what ranks after pass j-1's pick (a lower key, or
+    the same key at a higher column), takes the row max, and the lowest
+    column holding it.  Nothing is overwritten, so ``-inf`` padding ties
+    come out in ascending column order exactly like real ties.  Built from
+    compares, selects and lane max/min reductions: operations Mosaic
+    lowers (``lax.top_k``, ``take_along_axis`` and 3-D lane reshapes are
+    not).  Requires k <= TN.
     """
     tb, tn = scores.shape
-    w = tn // blocks
-    q = min(k, w)
-    cube = scores.reshape(tb, blocks, w)
-    bv, bw = jax.lax.top_k(cube, q)                   # (TB, C, q)
-    base = (jnp.arange(blocks, dtype=jnp.int32) * w)[None, :, None]
-    cand_v = bv.reshape(tb, blocks * q)
-    cand_i = (bw.astype(jnp.int32) + base).reshape(tb, blocks * q)
-    v, sel = jax.lax.top_k(cand_v, k)
-    return v, jnp.take_along_axis(cand_i, sel, axis=1)
+    key = _order_key(scores)
+    col = jax.lax.broadcasted_iota(jnp.int32, (tb, tn), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tb, k), 1)
+    lowest = jnp.iinfo(jnp.int32).min
+
+    def body(j, carry):
+        keys, cols, prev_k, prev_c = carry
+        after = (key < prev_k) | ((key == prev_k) & (col > prev_c))
+        kj = jnp.max(jnp.where(after, key, lowest), axis=1, keepdims=True)
+        cj = jnp.min(jnp.where(after & (key == kj), col, tn), axis=1,
+                     keepdims=True)
+        return (jnp.where(lane == j, kj, keys), jnp.where(lane == j, cj, cols),
+                kj, cj)
+
+    init = (jnp.zeros((tb, k), jnp.int32), jnp.zeros((tb, k), jnp.int32),
+            jnp.full((tb, 1), jnp.iinfo(jnp.int32).max, jnp.int32),
+            jnp.full((tb, 1), -1, jnp.int32))
+    keys, cols, _, _ = jax.lax.fori_loop(0, k, body, init)
+    vals = jax.lax.bitcast_convert_type(_order_key(keys), jnp.float32)
+    return vals, cols
 
 
 def pq_topk_fused_kernel(idx_ref, codes_ref, s_ref, *rest,
-                         k: int, tile: int, n_items: int, blocks: int,
+                         k: int, tile: int, n_items: int,
                          has_live: bool = False):
     if has_live:
         # Tombstone route (mutable catalogues): a (1, TN) int8 live row
@@ -184,9 +200,9 @@ def pq_topk_fused_kernel(idx_ref, codes_ref, s_ref, *rest,
         if has_live:
             ok = ok & (live_ref[...] != 0)            # (1, TN) broadcast
         scores = jnp.where(ok, scores, NEG_INF)
-        vals, cols = _tile_topk(scores, k, blocks)
-        out_v_ref[...] = vals[:, None, :]
-        out_i_ref[...] = (cols + tile_id * tile)[:, None, :]
+        vals, cols = _tile_topk(scores, k)
+        out_v_ref[...] = vals
+        out_i_ref[...] = cols + tile_id * tile
 
 
 def pq_scores_call(codes: jax.Array, s: jax.Array, *, tile: int = DEFAULT_TILE,
@@ -214,11 +230,13 @@ def pq_topk_fused_call(codes: jax.Array, s: jax.Array, k: int, *,
                        tile_idx: jax.Array, n_items: int,
                        tile: int = DEFAULT_TILE,
                        batch_tile: int = DEFAULT_BATCH_TILE,
-                       oversample: int = DEFAULT_OVERSAMPLE,
                        live: jax.Array = None,
                        interpret: bool = False):
-    """-> (vals (B, n_slots, K), ids (B, n_slots, K)) per-slot winners with
-    *global* item ids; merge outside.
+    """-> (vals (n_slots, B, K), ids (n_slots, B, K)) per-slot winners with
+    *global* item ids, slot-major; merge outside.  Slot-major because a
+    TPU block's last two dims must be (8, 128)-divisible or whole: the
+    (TB, K) block of one slot is, a (TB, 1, K) block of a (B, n_slots, K)
+    array is not.
 
     ``tile_idx`` (n_slots,) int32 selects which codes tile each grid slot
     scores (identity for the exhaustive route, a compacted survivor list for
@@ -231,9 +249,10 @@ def pq_topk_fused_call(codes: jax.Array, s: jax.Array, k: int, *,
     DMAs.  ``codes`` rows must cover every indexed tile; ``s``'s batch
     must divide by ``batch_tile``.
 
-    ``live`` (N/tile, tile) int8 is the optional tombstone mask, row t the
-    liveness of codes tile t (0 = delisted / padding).  It streams through
-    VMEM as a (1, tile) block under the SAME clamped index map as the
+    ``live`` (N/tile, 1, tile) int8 is the optional tombstone mask, row t
+    the liveness of codes tile t (0 = delisted / padding).  It streams
+    through VMEM as a whole (1, tile) block under the SAME clamped index
+    map as the
     codes tile, so each slot masks ITS tile's dead items to -inf inside
     the tile top-k; the sentinel-clamp contract (``-1`` -> block 0) is
     unchanged.  Extra HBM traffic: 1 byte/item — noise next to the m
@@ -243,13 +262,12 @@ def pq_topk_fused_call(codes: jax.Array, s: jax.Array, k: int, *,
     bq, m2, b = s.shape
     assert m == m2 and n % tile == 0
     assert bq % batch_tile == 0, (bq, batch_tile)
+    assert k <= tile, (k, tile)
     n_bt = bq // batch_tile
-    blocks = pick_blocks(tile, k, oversample)
     if live is not None:
-        assert live.shape == (n // tile, tile), (live.shape, n, tile)
+        assert live.shape == (n // tile, 1, tile), (live.shape, n, tile)
     kern = functools.partial(pq_topk_fused_kernel, k=k, tile=tile,
-                             n_items=n_items, blocks=blocks,
-                             has_live=live is not None)
+                             n_items=n_items, has_live=live is not None)
     # The 1D and 2D layouts share every block shape; they differ only in
     # grid order (1D: batch innermost so each codes tile is fetched once;
     # 2D: slots innermost so each group's S block is fetched once) and in
@@ -267,7 +285,7 @@ def pq_topk_fused_call(codes: jax.Array, s: jax.Array, k: int, *,
         slot, bt = (lambda i, j: i), (lambda i, j: j)
         codes_block = lambda i, j, idx_ref: jnp.maximum(idx_ref[i], 0)
     out_spec = pl.BlockSpec(
-        (batch_tile, 1, k), lambda a, c, idx_ref: (bt(a, c), slot(a, c), 0))
+        (None, batch_tile, k), lambda a, c, idx_ref: (slot(a, c), bt(a, c), 0))
     in_specs = [
         pl.BlockSpec((tile, m),
                      lambda a, c, idx_ref: (codes_block(a, c, idx_ref),
@@ -280,8 +298,8 @@ def pq_topk_fused_call(codes: jax.Array, s: jax.Array, k: int, *,
         # Same clamped tile index map as codes: sentinel slots re-read an
         # already-fetched live row exactly like they re-read codes block 0.
         in_specs.append(pl.BlockSpec(
-            (1, tile),
-            lambda a, c, idx_ref: (codes_block(a, c, idx_ref), 0)))
+            (None, 1, tile),
+            lambda a, c, idx_ref: (codes_block(a, c, idx_ref), 0, 0)))
         operands.append(live)
     grid_spec = compat.prefetch_scalar_grid_spec(
         num_scalar_prefetch=1,
@@ -293,8 +311,8 @@ def pq_topk_fused_call(codes: jax.Array, s: jax.Array, k: int, *,
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((bq, n_slots, k), jnp.float32),
-            jax.ShapeDtypeStruct((bq, n_slots, k), jnp.int32),
+            jax.ShapeDtypeStruct((n_slots, bq, k), jnp.float32),
+            jax.ShapeDtypeStruct((n_slots, bq, k), jnp.int32),
         ],
         interpret=interpret,
     )(tile_idx.astype(jnp.int32), *operands)

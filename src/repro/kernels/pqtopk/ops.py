@@ -85,13 +85,16 @@ def _pad_batch(s: jax.Array, batch_tile: int) -> jax.Array:
     return s
 
 
-def _merge_slot_winners(tv: jax.Array, ti: jax.Array, k: int):
-    """(B, n_slots, K) per-slot winners -> global (B, k).  Slots are in
-    ascending tile order, so the stable ``lax.top_k`` over the flattened
-    candidates breaks ties by lowest global id, matching the oracle."""
-    bq, slots, kk = tv.shape
-    fv, fi = jax.lax.top_k(tv.reshape(bq, slots * kk), k)
-    return fv, jnp.take_along_axis(ti.reshape(bq, slots * kk), fi, axis=1)
+def _merge_slot_winners(tv: jax.Array, ti: jax.Array, k: int, bq: int):
+    """Slot-major (n_slots, B_pad, K) per-slot winners -> global (bq, k).
+    Each row's candidates are laid out slot by slot in ascending tile
+    order, so the stable ``lax.top_k`` over them breaks ties by lowest
+    global id, matching the oracle."""
+    slots, _, kk = tv.shape
+    tv = jnp.transpose(tv[:, :bq], (1, 0, 2)).reshape(bq, slots * kk)
+    ti = jnp.transpose(ti[:, :bq], (1, 0, 2)).reshape(bq, slots * kk)
+    fv, fi = jax.lax.top_k(tv, k)
+    return fv, jnp.take_along_axis(ti, fi, axis=1)
 
 
 def _remap_dead(fv: jax.Array, fi: jax.Array, n: int):
@@ -137,7 +140,7 @@ def pq_topk(codes: jax.Array, s: jax.Array, k: int, *,
     tv, ti = _k.pq_topk_fused_call(padded, _pad_batch(s, bt), k,
                                    tile_idx=idx, n_items=n, tile=tile,
                                    batch_tile=bt, interpret=interpret)
-    return _merge_slot_winners(tv[:bq], ti[:bq], k)
+    return _merge_slot_winners(tv, ti, k, bq)
 
 
 def _pq_topk_tiles(codes: jax.Array, s: jax.Array, k: int,
@@ -167,7 +170,7 @@ def _pq_topk_tiles(codes: jax.Array, s: jax.Array, k: int,
         pad = padded.shape[0] - n
         if pad:
             lv = jnp.pad(lv, (0, pad))      # padding + sentinel tile: dead
-        live2 = lv.reshape(-1, tile)
+        live2 = lv.reshape(-1, 1, tile)
     bt = effective_batch_tile(bq, batch_tile)
     grouped = tile_idx.ndim == 2
     if grouped and tile_idx.shape[0] * bt < bq:
@@ -179,7 +182,7 @@ def _pq_topk_tiles(codes: jax.Array, s: jax.Array, k: int,
                                        tile_idx=tile_idx, n_items=n,
                                        tile=tile, batch_tile=bt,
                                        live=live2, interpret=interpret)
-        fv, fi = _merge_slot_winners(tv[:bq], ti[:bq], k)
+        fv, fi = _merge_slot_winners(tv, ti, k, bq)
         if live is not None:
             fv, fi = _remap_dead(fv, fi, n)
         return fv, fi

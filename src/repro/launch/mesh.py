@@ -11,12 +11,22 @@ touch jax device state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the compiler propagates
+    shardings and ``with_sharding_constraint`` hints them (``Explicit``
+    axes, the default since jax 0.9, reject such hints)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(*, multi_pod: bool = False):
@@ -26,10 +36,10 @@ def make_test_mesh(*, multi_pod: bool = False):
         pod = 2 if n % 2 == 0 and n >= 2 else 1
         rest = n // pod
         data = _largest_factor(rest)
-        return jax.make_mesh((pod, data, rest // data),
-                             ("pod", "data", "model"))
+        return make_mesh((pod, data, rest // data),
+                         ("pod", "data", "model"))
     data = _largest_factor(n)
-    return jax.make_mesh((data, n // data), ("data", "model"))
+    return make_mesh((data, n // data), ("data", "model"))
 
 
 def _largest_factor(n: int) -> int:

@@ -11,6 +11,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import place_compile_cache
 from repro.configs.base import get_config, get_reduced
 from repro.serving.engine import Request, RetrievalEngine
 
@@ -350,6 +351,7 @@ def main(argv=None):
                          "it must recover from the log before the health "
                          "FSM re-admits it (repeatable)")
     args = ap.parse_args(argv)
+    place_compile_cache()
 
     arch = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     assert arch.family == "seqrec", "serve.py drives the seqrec archs"
@@ -525,6 +527,11 @@ def main(argv=None):
         print(f"ladder={engine.ladder} "
               f"rung_hit_fraction={stats['rung_hit_fraction']:.2f} "
               f"rung_counts={stats['rung_counts']}")
+    # A shed or late request is a failed run, unless --fail-at injected
+    # the failures that shed it.
+    if not args.fail_at and (stats["shed"] or stats["timeouts"]):
+        raise SystemExit(f"FAIL: {int(stats['shed'])} shed and "
+                         f"{int(stats['timeouts'])} timed-out requests")
     return results
 
 

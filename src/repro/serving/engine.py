@@ -21,7 +21,9 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.distributed.sharding import auto_axes
 from repro.training.fault_tolerance import (SimulatedFailure,
                                             StragglerMonitor)
 
@@ -159,6 +161,7 @@ class RetrievalEngine:
                  max_batch: int = 64, method: Optional[str] = None,
                  jit_serve: bool = True, ladder: Optional[Tuple[int, ...]] = None,
                  head_state: Optional[Any] = None,
+                 params: Optional[Any] = None,
                  faults: Optional[Any] = None, max_retries: int = 2,
                  retry_backoff_ms: float = 1.0,
                  straggler_factor: float = 3.0,
@@ -204,6 +207,13 @@ class RetrievalEngine:
         catalogue mutation servable (docs/PRUNING.md §Catalogue
         mutation).
 
+        ``params`` is a pytree of arrays ``serve_fn`` takes as its third
+        argument in the same way, but fixed for the engine's life (no
+        swap).  Passed as data, model weights and catalogue are not
+        compiled into the executables as constants, and they keep the
+        placement they were given: a catalogue split over a mesh stays
+        split, each device holding its own rows.
+
         ``faults`` (a ``ServeFaultInjector``) plus ``max_retries`` /
         ``retry_backoff_ms`` give :meth:`run_once` graceful degradation:
         a failed dispatch retries with exponential backoff, exhausted
@@ -230,6 +240,8 @@ class RetrievalEngine:
             self._fn_pinned = (jax.jit(serve_fn_pinned, static_argnums=(1,))
                                if jit_serve else serve_fn_pinned)
         self._compiled: Dict[Tuple[int, int, Optional[str]], Callable] = {}
+        self._executables: Dict[Tuple[int, int, Optional[str], bool],
+                                Any] = {}
         self.seq_len = seq_len
         self.k = k
         self.max_k = k if max_k is None else max(max_k, k)
@@ -239,13 +251,20 @@ class RetrievalEngine:
         self.batcher = MicroBatcher(max_batch=max_batch)
         self.latencies_ms: List[float] = []
         self.timeouts = 0
-        self._head_state = head_state
+        if head_state is not None and params is not None:
+            raise ValueError("pass head_state (swappable) or params "
+                             "(fixed), not both")
+        # Arrays threaded into every dispatch as serve_fn's third arg.
+        self._data = head_state if head_state is not None else params
+        self._swappable = head_state is not None
         self._head_treedef = None
         self._head_sds = None
-        if head_state is not None:
-            self._head_treedef = jax.tree_util.tree_structure(head_state)
+        if self._data is not None:
+            self._head_treedef = jax.tree_util.tree_structure(self._data)
             self._head_sds = jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), head_state)
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype, sharding=getattr(a, "sharding", None)),
+                self._data)
         self.faults = faults
         self.max_retries = max_retries
         self.retry_backoff_ms = retry_backoff_ms
@@ -295,6 +314,8 @@ class RetrievalEngine:
         from repro.kernels.pqtopk import kernel as pqtopk_kernel
         from repro.models import seqrec as seqrec_lib
         method = method or getattr(cfg, "serve_method", "pqtopk")
+        if sharded_mesh is not None:
+            sharded_mesh = auto_axes(sharded_mesh)
         # Largest k any route built here can serve: bounded by the
         # catalogue, and for the fused-kernel-backed routes also by the
         # kernel's item tile (pq_topk / pq_topk_tiles reject k > tile).
@@ -327,9 +348,21 @@ class RetrievalEngine:
                 ladder = pruning.calibrate_ladder(counts, t, k, state.tile)
 
         with_rung = method == "pqtopk_pruned" and ladder is not None
+        if sharded_mesh is not None:
+            # Weights on every device of the mesh; on the pruned route
+            # each device holds only its own slice of the catalogue.
+            split = method == "pqtopk_pruned"
+            placed = jax.device_put(
+                {k: v for k, v in params.items()
+                 if not (split and k == "item_emb")},
+                NamedSharding(sharded_mesh, P()))
+            if split:
+                placed["item_emb"] = retrieval_head.place_sharded(
+                    params["item_emb"], sharded_mesh)
+            params = placed
 
-        def serve_fn(seqs, kk):
-            return seqrec_lib.serve_topk(params, seqs, cfg, k=kk,
+        def serve_fn(seqs, kk, p):
+            return seqrec_lib.serve_topk(p, seqs, cfg, k=kk,
                                          method=method,
                                          sharded_mesh=sharded_mesh,
                                          ladder=ladder,
@@ -348,15 +381,15 @@ class RetrievalEngine:
                 if retrieval_head.is_pq(params["item_emb"]) else None
             n_tiles = getattr(state, "n_tiles", None)
             if n_tiles is None or min(ladder) < n_tiles:
-                def serve_fn_pinned(seqs, kk):
-                    return seqrec_lib.serve_topk(params, seqs, cfg, k=kk,
+                def serve_fn_pinned(seqs, kk, p):
+                    return seqrec_lib.serve_topk(p, seqs, cfg, k=kk,
                                                  method=method,
                                                  ladder=ladder,
                                                  pin_rung=True)
 
         return cls(serve_fn, seq_len=cfg.max_seq_len, k=k, max_k=max_k,
                    max_batch=max_batch, method=method, ladder=ladder,
-                   faults=faults, max_retries=max_retries,
+                   params=params, faults=faults, max_retries=max_retries,
                    retry_backoff_ms=retry_backoff_ms,
                    serve_fn_pinned=serve_fn_pinned)
 
@@ -439,18 +472,19 @@ class RetrievalEngine:
         pq = getattr(cfg, "pq", None)
         grouped = pq is not None and pq.query_grouping and pq.n_groups > 1
 
-        def count_fn(seqs):
+        st = state
+        if state.shards > 1:
+            # Flat counts from a per-shard layout would misread tile
+            # boundaries; bound each shard's tile block independently
+            # (same layout the sharded cascade sees) and sum.
+            st = pruning.build_pruned_state(
+                head["codes"], state.b, state.tile, backend=state.backend)
+
+        def count_fn(params, st, seqs):
+            head = params["item_emb"]
             phi = seqrec_lib.sequence_embedding(params, seqs, cfg)
             s = scoring.subid_scores(head["sub_emb"].astype(jnp.float32),
                                      phi.astype(jnp.float32))
-            st = state
-            if state.shards > 1:
-                # Flat counts from a per-shard layout would misread tile
-                # boundaries; bound each shard's tile block independently
-                # (same layout the sharded cascade sees) and sum.
-                st = pruning.build_pruned_state(
-                    head["codes"], state.b, state.tile,
-                    backend=state.backend)
             live = head.get("live")
             if grouped:
                 # Group-aware observable: the grouped ladder escalates on
@@ -469,7 +503,7 @@ class RetrievalEngine:
                 seqs = rng.integers(
                     1, cfg.n_items + 1,
                     (bsz, cfg.max_seq_len)).astype(np.int32)
-                counts.append(int(fn(jnp.asarray(seqs))))
+                counts.append(int(fn(params, st, jnp.asarray(seqs))))
         return counts
 
     def submit(self, req: Request):
@@ -510,16 +544,18 @@ class RetrievalEngine:
             if self._jit_serve:
                 sds = jax.ShapeDtypeStruct((bucket, self.seq_len), jnp.int32)
                 try:
-                    if self._head_state is not None:
+                    if self._data is not None:
                         # Head arrays are DATA: lower against their
-                        # shapes/dtypes once, read ``self._head_state``
-                        # late at every call so swap_head_state takes
-                        # effect with zero recompiles.
+                        # shapes/dtypes/placement once, read
+                        # ``self._data`` late at every call so
+                        # swap_head_state takes effect with zero
+                        # recompiles.
                         exe = jfn.lower(sds, kk, self._head_sds).compile()
-                        fn = lambda seqs, _e=exe: _e(seqs, self._head_state)
+                        fn = lambda seqs, _e=exe: _e(seqs, self._data)
                     else:
                         exe = jfn.lower(sds, kk).compile()
                         fn = lambda seqs, _e=exe: _e(seqs)
+                    self._executables[key] = exe
                 except (jax.errors.TracerArrayConversionError,
                         jax.errors.TracerBoolConversionError,
                         jax.errors.ConcretizationTypeError):
@@ -529,18 +565,44 @@ class RetrievalEngine:
                     # Genuine compile failures (OOM, lowering bugs) are NOT
                     # swallowed: they raise here, before any request of the
                     # batch is half-served, and never inflate n_compiles.
-                    if self._head_state is not None:
+                    if self._data is not None:
                         fn = lambda seqs, _k=kk, _f=jfn: _f(
-                            seqs, _k, self._head_state)
+                            seqs, _k, self._data)
                     else:
                         fn = lambda seqs, _k=kk, _f=jfn: _f(seqs, _k)
-            elif self._head_state is not None:
+            elif self._data is not None:
                 fn = lambda seqs, _k=kk, _f=sfn: _f(
-                    seqs, _k, self._head_state)
+                    seqs, _k, self._data)
             else:
                 fn = lambda seqs, _k=kk, _f=sfn: _f(seqs, _k)
             self._compiled[key] = fn
         return fn
+
+    def warmup(self, buckets: Sequence[int], ks: Sequence[int] = (),
+               pinned: bool = False) -> None:
+        """AOT-compile the serve variant of every padding bucket that
+        batch sizes ``buckets`` fall in, at the engine's k and any client
+        ``ks``, before traffic arrives: a cold compile inside the serving
+        loop would be charged to the first requests' latency."""
+        kks = {self.batch_k([k]) for k in set(ks) | {self.k}}
+        for b in buckets:
+            for kk in kks:
+                self._variant(MicroBatcher.bucket(b, self.batcher.max_batch),
+                              kk, pinned=pinned)
+
+    @property
+    def data(self) -> Optional[Any]:
+        """The arrays threaded into every dispatch (``params`` or the
+        swappable ``head_state``), as placed on the device(s)."""
+        return self._data
+
+    def compiled_hlo(self) -> Dict[Tuple[int, int, Optional[str], bool],
+                                   str]:
+        """Optimised HLO text of every AOT-compiled serve variant, by
+        variant key: what the device runs.  A Pallas kernel shows up as a
+        ``tpu_custom_call`` when compiled for a TPU."""
+        return {key: exe.as_text()
+                for key, exe in self._executables.items()}
 
     @property
     def has_pinned(self) -> bool:
@@ -560,7 +622,7 @@ class RetrievalEngine:
         pow2-capacity design in ``core.mutation`` exists precisely so
         live churn never trips this check; a capacity *growth* must build
         a new engine (a new compile is then honest and expected)."""
-        if self._head_state is None:
+        if not self._swappable:
             raise ValueError(
                 "engine was not built with a swappable head; use "
                 "for_seqrec_mutable (or pass head_state=) to enable "
@@ -581,7 +643,7 @@ class RetrievalEngine:
                     f"{old.shape}/{old.dtype} to {new.shape}/{new.dtype}; "
                     "capacity and dtypes are compile-static — rebuild the "
                     "engine to grow the catalogue")
-        self._head_state = jax.tree_util.tree_unflatten(treedef, leaves)
+        self._data = jax.tree_util.tree_unflatten(treedef, leaves)
         self.n_swaps += 1
 
     def _shed_result(self, r: Request, now: float,
@@ -773,7 +835,7 @@ class RetrievalEngine:
             "shed": float(self.shed),
             "stragglers": float(len(self.straggler_monitor.flagged)),
         }
-        if self._head_state is not None:
+        if self._swappable:
             out["n_swaps"] = float(self.n_swaps)
         if self.ladder is not None:
             # Fraction of served batches that stayed on a non-exhaustive

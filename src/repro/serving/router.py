@@ -317,15 +317,11 @@ class ReplicaRouter:
         without warmup the first batches straggle behind multi-second
         compiles, the hedger fires on compile noise, and a latency
         benchmark measures XLA, not serving."""
+        bks = set(buckets) | {self.batcher.max_batch}
         for eng in self.engines:
-            bks = set(buckets) | {self.batcher.max_batch}
-            kks = {eng.batch_k([k]) for k in set(ks) | {eng.k}}
-            for b in bks:
-                bb = MicroBatcher.bucket(b, eng.batcher.max_batch)
-                for kk in kks:
-                    eng._variant(bb, kk)
-                    if eng.has_pinned:
-                        eng._variant(bb, kk, pinned=True)
+            eng.warmup(bks, ks)
+            if eng.has_pinned:
+                eng.warmup(bks, ks, pinned=True)
 
     # ------------------------------------------------------------------
     # worker side (one thread per replica; the only code touching engines)

@@ -155,3 +155,45 @@ def test_engine_method_defaults_to_config():
     params = S.init_seqrec(jax.random.PRNGKey(0), cfg)
     eng = RetrievalEngine.for_seqrec(params, cfg)
     assert eng.method == cfg.serve_method
+
+
+def _levelwise_sum(parts):
+    """Reference order: pair neighbours level by level, the odd one
+    carried up."""
+    parts = list(parts)
+    while len(parts) > 1:
+        nxt = [parts[i] + parts[i + 1] for i in range(0, len(parts) - 1, 2)]
+        if len(parts) % 2:
+            nxt.append(parts[-1])
+        parts = nxt
+    return parts[0]
+
+
+@pytest.mark.parametrize("n", range(1, 18))
+def test_tree_sum_lazy_keeps_the_levelwise_order(n):
+    """The lazy reduction the kernel uses rounds exactly like the level-
+    by-level pairing (values spread over many magnitudes, so any other
+    association would change low bits)."""
+    from repro.core.scoring import tree_sum, tree_sum_lazy
+    rng = np.random.default_rng(n)
+    parts = [np.float32(v) for v in
+             rng.normal(size=n) * 10.0 ** rng.integers(-6, 7, n)]
+    want = _levelwise_sum(parts)
+    assert tree_sum(parts) == want
+    assert tree_sum_lazy(lambda i: parts[i], n) == want
+
+
+def test_bf16_split_rebuilds_f32_exactly():
+    """hi + mid + lo of the kernel's bf16 split is the f32 input, bit for
+    bit, across magnitudes and signs — what makes the one-hot MXU scores
+    exact."""
+    from repro.kernels.pqtopk.kernel import _bf16_split
+    rng = np.random.default_rng(0)
+    x = (rng.normal(size=(64, 512)) * 10.0 ** rng.integers(-20, 20, (64, 512))
+         ).astype(np.float32)
+    x[0, :4] = [0.0, -0.0, 1.0, -3.0]
+    hi, mid, lo = _bf16_split(jnp.asarray(x))
+    assert hi.dtype == mid.dtype == lo.dtype == jnp.bfloat16
+    back = (hi.astype(jnp.float32) + mid.astype(jnp.float32)) \
+        + lo.astype(jnp.float32)
+    np.testing.assert_array_equal(np.asarray(back), x)

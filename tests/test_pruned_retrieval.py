@@ -170,16 +170,24 @@ def test_fused_kernel_batch_tiled_parity(dtype):
     np.testing.assert_array_equal(np.asarray(i), np.asarray(i_ref))
 
 
-def test_fused_kernel_single_pass_blocks():
-    """pick_blocks: k-oversampled, power-of-two, divides the tile."""
-    from repro.kernels.pqtopk.kernel import pick_blocks
-    assert pick_blocks(2048, 10) == 32          # >= 2*k, pow2
-    assert pick_blocks(2048, 100) == 128        # capped at lane width
-    assert pick_blocks(128, 10) == 32
-    for tile in (128, 256, 2048):
-        for k in (1, 5, 64, 128):
-            c = pick_blocks(tile, k)
-            assert tile % c == 0 and c >= 1
+@pytest.mark.parametrize("k", [1, 7, 64, 128])
+@pytest.mark.parametrize("layout", ["distinct", "ties", "neg_inf_tail"])
+def test_tile_topk_matches_lax_top_k(k, layout):
+    """The kernel's in-VMEM max-extract top-k returns exactly what
+    ``lax.top_k`` returns — values, and columns in value-then-lowest-
+    column order — including heavy ties and a ``-inf`` masked tail, where
+    padding columns must come out in ascending order, never repeated."""
+    from repro.kernels.pqtopk.kernel import _tile_topk
+    rng = np.random.default_rng(k)
+    scores = rng.normal(size=(8, 128)).astype(np.float32)
+    if layout == "ties":
+        scores = np.round(scores * 2) / 2           # few distinct values
+    elif layout == "neg_inf_tail":
+        scores[:, 5:] = -np.inf
+    v, c = jax.jit(_tile_topk, static_argnums=1)(jnp.asarray(scores), k)
+    v_ref, c_ref = jax.lax.top_k(jnp.asarray(scores), k)
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(v_ref))
+    np.testing.assert_array_equal(np.asarray(c), np.asarray(c_ref))
 
 
 def test_pq_topk_tiles_sentinel_padding():
