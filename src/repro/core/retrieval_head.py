@@ -165,8 +165,9 @@ def top_items(params: Params, phi: jax.Array, k: int,
     if method == "pqtopk_fused":
         if not is_pq(params):
             raise ValueError("method 'pqtopk_fused' requires a PQ head")
-        s = scoring.subid_scores(params["sub_emb"].astype(jnp.float32),
-                                 phi.astype(jnp.float32))
+        with jax.named_scope("head.table"):
+            s = scoring.subid_scores(params["sub_emb"].astype(jnp.float32),
+                                     phi.astype(jnp.float32))
         from repro.kernels.pqtopk import ops as kernel_ops
         return kernel_ops.pq_topk(params["codes"], s, k)
     if method == "pqtopk_pruned":

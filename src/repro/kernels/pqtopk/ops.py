@@ -134,13 +134,21 @@ def pq_topk(codes: jax.Array, s: jax.Array, k: int, *,
     tile = min(tile, _round_up(n, 128))
     if k > tile:
         raise ValueError(f"k={k} > tile={tile}")
-    padded = _pad_codes(codes, tile)
-    idx = jnp.arange(padded.shape[0] // tile, dtype=jnp.int32)
     bt = effective_batch_tile(bq, batch_tile)
-    tv, ti = _k.pq_topk_fused_call(padded, _pad_batch(s, bt), k,
-                                   tile_idx=idx, n_items=n, tile=tile,
-                                   batch_tile=bt, interpret=interpret)
-    return _merge_slot_winners(tv, ti, k, bq)
+    # Named scopes tag each part's device ops in the HLO's op_name
+    # metadata.  The TPU compiler names the kernel's custom call after the
+    # innermost scope around it, so that scope is ``pq_topk``: the call
+    # stays ``%pq_topk.<n>``, the name a device trace finds the kernel by.
+    with jax.named_scope("head.codes_pad"):
+        padded = _pad_codes(codes, tile)
+        s = _pad_batch(s, bt)
+    with jax.named_scope("head.kernel"), jax.named_scope("pq_topk"):
+        idx = jnp.arange(padded.shape[0] // tile, dtype=jnp.int32)
+        tv, ti = _k.pq_topk_fused_call(padded, s, k, tile_idx=idx,
+                                       n_items=n, tile=tile, batch_tile=bt,
+                                       interpret=interpret)
+    with jax.named_scope("head.merge"):
+        return _merge_slot_winners(tv, ti, k, bq)
 
 
 def _pq_topk_tiles(codes: jax.Array, s: jax.Array, k: int,

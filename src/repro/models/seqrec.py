@@ -169,7 +169,8 @@ def serve_topk(params: Params, item_seq: jax.Array, cfg: SeqRecConfig, *,
     served through it must be tagged), and whether to additionally return
     the rung taken (i32 scalar — still one dispatch; the serving engine
     uses it to track ``rung_hit_fraction``)."""
-    phi = constrain(sequence_embedding(params, item_seq, cfg), "phi")
+    with jax.named_scope("backbone"):
+        phi = constrain(sequence_embedding(params, item_seq, cfg), "phi")
     if method != "pqtopk_pruned" and return_rung:
         raise ValueError("return_rung is only meaningful for the pruned "
                          "cascade (method='pqtopk_pruned')")

@@ -10,10 +10,17 @@ Two engines share the queue/batcher machinery:
 
 Both apply deadline-based request timeouts (serving-side straggler
 mitigation, same policy knob as training's StragglerMonitor).
+
+``RetrievalEngine`` keeps one :class:`BatchSpan` per completed batch and
+marks its three calls as profiler spans (``serve.prepare``,
+``serve.launch``, ``serve.complete`` with ``serve.wait`` and
+``serve.fetch`` inside), so a ``jax.profiler`` trace shows what the
+engine's host was doing on the device ops' clock (docs/SERVING.md).
 """
 from __future__ import annotations
 
 import collections
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -126,6 +133,38 @@ class MicroBatcher:
         return min(b, max_batch)
 
 
+#: How many of the latest completed batches ``RetrievalEngine`` keeps a
+#: ``BatchSpan`` of, and how many request latencies it keeps for
+#: ``stats()``: about half a minute of traffic at 2,000 requests/s.
+SPAN_WINDOW = 2 ** 16
+
+
+@dataclass(slots=True)
+class BatchSpan:
+    """One completed batch at the engine's boundaries, on the host's
+    monotonic clock (seconds).  ``prepare`` runs from ``t_prepare`` to
+    ``t_prepared``, ``launch`` from ``t_launch`` to ``t_launched`` (the
+    last attempt's, under retries) and ``complete`` from ``t_complete`` to
+    ``t_done``: it waits for the device until ``t_ready`` (the batch's
+    request latencies are timed to it) and has the results on the host at
+    ``t_fetched``.  ``queue_s`` sums ``t_prepare - Request.arrival`` over
+    the batch's ``rows`` live requests; ``bucket`` is the padded rows sent
+    to the device and ``kk`` the batch's compiled k."""
+    batch_index: int
+    rows: int
+    bucket: int
+    kk: int
+    queue_s: float
+    t_prepare: float
+    t_prepared: float = 0.0
+    t_launch: float = 0.0
+    t_launched: float = 0.0
+    t_complete: float = 0.0
+    t_ready: float = 0.0
+    t_fetched: float = 0.0
+    t_done: float = 0.0
+
+
 @dataclass
 class PreparedBatch:
     """Host-side work of one dispatch, done: deadline-shed applied (twice
@@ -138,6 +177,7 @@ class PreparedBatch:
     fn: Callable                      # compiled variant (takes seqs)
     kk: int                           # trace-static batch k
     batch_index: int
+    span: BatchSpan                   # filled in by launch and complete
     degraded: str = ""
 
 
@@ -149,7 +189,6 @@ class InFlightBatch:
     while the host pads/dispatches the next batch."""
     prep: PreparedBatch
     out: Any
-    t0: float
     straggler: bool = False           # set by complete()
 
 
@@ -249,7 +288,14 @@ class RetrievalEngine:
         self.ladder = None if ladder is None else tuple(ladder)
         self.rung_counts: collections.Counter = collections.Counter()
         self.batcher = MicroBatcher(max_batch=max_batch)
-        self.latencies_ms: List[float] = []
+        # The latest SPAN_WINDOW request latencies and batch records;
+        # n_timed and n_batches count every one since the engine started.
+        self.latencies_ms: collections.deque[float] = collections.deque(
+            maxlen=SPAN_WINDOW)
+        self.batch_spans: collections.deque[BatchSpan] = collections.deque(
+            maxlen=SPAN_WINDOW)
+        self.n_timed = 0
+        self.n_batches = 0
         self.timeouts = 0
         if head_state is not None and params is not None:
             raise ValueError("pass head_state (swappable) or params "
@@ -361,6 +407,8 @@ class RetrievalEngine:
                     params["item_emb"], sharded_mesh)
             params = placed
 
+        # Jitted, this closure is the module ``jit_serve_fn``: the name a
+        # device trace (and the benchmark) finds the serve step by.
         def serve_fn(seqs, kk, p):
             return seqrec_lib.serve_topk(p, seqs, cfg, k=kk,
                                          method=method,
@@ -653,10 +701,12 @@ class RetrievalEngine:
         self.shed += 1
         self.timeouts += int(timed_out)
         self.latencies_ms.append(lat)
+        self.n_timed += 1
         return Result(r.request_id, np.empty(0, np.int32),
                       np.empty(0, np.float32), lat, timed_out=timed_out,
                       shed=True, degraded=degraded)
 
+    @functools.partial(jax.profiler.annotate_function, name="serve.prepare")
     def prepare(self, reqs: List[Request], *, k_cap: Optional[int] = None,
                 rung_pin: bool = False,
                 ) -> Tuple[List[Result], Optional[PreparedBatch]]:
@@ -683,14 +733,17 @@ class RetrievalEngine:
         # its deadline would burn a batch slot producing an answer nobody
         # is waiting for — and worse, widen the padding bucket for the
         # requests that are still alive.
-        now = time.monotonic()
+        t_prepare = now = time.monotonic()
         results: List[Result] = []
         alive: List[Request] = []
+        queue_s = 0.0
         for r in reqs:
-            if (now - r.arrival) * 1e3 > r.deadline_ms:
+            waited = now - r.arrival
+            if waited * 1e3 > r.deadline_ms:
                 results.append(self._shed_result(r, now))
             else:
                 alive.append(r)
+                queue_s += waited
         if not alive:
             return results, None
         bucket = MicroBatcher.bucket(len(alive), self.batcher.max_batch)
@@ -716,6 +769,7 @@ class RetrievalEngine:
         for r in alive:
             if (now - r.arrival) * 1e3 > r.deadline_ms:
                 results.append(self._shed_result(r, now))
+                queue_s -= t_prepare - r.arrival
             else:
                 survivors.append(r)
         if not survivors:
@@ -724,9 +778,14 @@ class RetrievalEngine:
         for i, r in enumerate(survivors):
             s = np.asarray(r.payload)[-self.seq_len:]
             seqs[i, -len(s):] = s
-        return results, PreparedBatch(survivors, jnp.asarray(seqs), fn, kk,
-                                      batch_index, degraded="+".join(tags))
+        span = BatchSpan(batch_index, len(survivors), bucket, kk, queue_s,
+                         t_prepare)
+        prep = PreparedBatch(survivors, jnp.asarray(seqs), fn, kk,
+                             batch_index, span, degraded="+".join(tags))
+        span.t_prepared = time.monotonic()
+        return results, prep
 
+    @functools.partial(jax.profiler.annotate_function, name="serve.launch")
     def launch(self, prep: PreparedBatch) -> InFlightBatch:
         """Dispatch a prepared batch asynchronously.  The returned handle's
         ``out`` is an in-flight device computation — the caller overlaps
@@ -734,11 +793,15 @@ class RetrievalEngine:
         :meth:`complete` to block.  Injected faults
         (``ServeFaultInjector.check``) raise here, before dispatch, so
         the caller's retry loop sees them."""
+        span = prep.span
+        span.t_launch = time.monotonic()
         if self.faults is not None:
             self.faults.check(prep.batch_index)
-        t0 = time.monotonic()
-        return InFlightBatch(prep, prep.fn(prep.seqs), t0)
+        inflight = InFlightBatch(prep, prep.fn(prep.seqs))
+        span.t_launched = time.monotonic()
+        return inflight
 
+    @functools.partial(jax.profiler.annotate_function, name="serve.complete")
     def complete(self, inflight: InFlightBatch) -> List[Result]:
         """Block until the dispatched batch has actually finished, then
         timestamp and slice per-request results.
@@ -748,23 +811,28 @@ class RetrievalEngine:
         measure enqueue cost, not completion — latency accounting and the
         straggler monitor would both read near-zero for a slow kernel."""
         prep = inflight.prep
-        out = jax.block_until_ready(inflight.out)
+        span = prep.span
+        span.t_complete = time.monotonic()
+        with jax.profiler.TraceAnnotation("serve.wait"):
+            out = jax.block_until_ready(inflight.out)
         if self.faults is not None:
             delay = self.faults.delay_s(prep.batch_index)
             if delay:
                 time.sleep(delay)  # synthetic straggler, lands in elapsed
-        now = time.monotonic()
+        span.t_ready = now = time.monotonic()
         inflight.straggler = self.straggler_monitor.record(
-            prep.batch_index, now - inflight.t0)
-        if len(out) == 3:
-            # Ladder-enabled pruned route: third output is the rung taken
-            # (an i32 scalar riding the same dispatch) — tally it so
-            # stats() can report rung_hit_fraction.
-            ids, scores, rung = out
-            self.rung_counts[int(rung)] += 1
-        else:
-            ids, scores = out
-        ids, scores = np.asarray(ids), np.asarray(scores)
+            prep.batch_index, now - span.t_launch)
+        with jax.profiler.TraceAnnotation("serve.fetch"):
+            if len(out) == 3:
+                # Ladder-enabled pruned route: third output is the rung
+                # taken (an i32 scalar riding the same dispatch) — tally
+                # it so stats() can report rung_hit_fraction.
+                ids, scores, rung = out
+                self.rung_counts[int(rung)] += 1
+            else:
+                ids, scores = out
+            ids, scores = np.asarray(ids), np.asarray(scores)
+        span.t_fetched = time.monotonic()
         results: List[Result] = []
         for i, r in enumerate(prep.requests):
             lat = (now - r.arrival) * 1e3
@@ -775,6 +843,10 @@ class RetrievalEngine:
             results.append(Result(r.request_id, ids[i, :rk],
                                   scores[i, :rk], lat, timed_out,
                                   degraded=prep.degraded))
+        self.n_timed += len(prep.requests)
+        self.n_batches += 1
+        span.t_done = time.monotonic()
+        self.batch_spans.append(span)
         return results
 
     def run_once(self, *, k_cap: Optional[int] = None,
@@ -822,10 +894,14 @@ class RetrievalEngine:
         # No traffic yet -> None, NOT 0.0: a placeholder zero is a real
         # latency to any aggregator averaging across replicas and would
         # drag fleet percentiles toward zero.
+        # mRT/p99 and the batch means cover the latest SPAN_WINDOW
+        # requests and batches; count and batches are totals.
         lat = (np.asarray(self.latencies_ms) if self.latencies_ms
                else None)
+        spans = self.batch_spans
+        rows = sum(s.rows for s in spans)
         out: Dict[str, Any] = {
-            "count": float(len(self.latencies_ms)),
+            "count": float(self.n_timed),
             "mRT_ms": float(np.median(lat)) if lat is not None else None,
             "p99_ms": (float(np.percentile(lat, 99))
                        if lat is not None else None),
@@ -834,6 +910,17 @@ class RetrievalEngine:
             "retried": float(self.retried),
             "shed": float(self.shed),
             "stragglers": float(len(self.straggler_monitor.flagged)),
+            # Mean wait of a request from arrival to its batch's prepare.
+            "queue_ms": (1e3 * sum(s.queue_s for s in spans) / rows
+                         if spans else None),
+            # Mean time a batch was in flight: from its launch returning
+            # to complete's wait ending (its device run, behind whatever
+            # was on the device before it).
+            "flight_ms": (1e3 * sum(s.t_ready - s.t_launched for s in spans)
+                          / len(spans) if spans else None),
+            # Live rows over the padded rows sent to the device.
+            "fill": rows / sum(s.bucket for s in spans) if spans else None,
+            "batches": float(self.n_batches),
         }
         if self._swappable:
             out["n_swaps"] = float(self.n_swaps)

@@ -93,3 +93,26 @@ def test_fused_kernel_compiles_for_v5e(width, table, batch_tile, one_chip,
     out_v, out_i = compiled.out_info
     n_slots = args[2].shape[-1]
     assert out_v.shape == out_i.shape == (n_slots, bq, K)
+
+
+@pytest.mark.parametrize("width", WIDTHS, ids=[w[0] for w in WIDTHS])
+def test_kernel_call_keeps_its_name_inside_the_scopes(width, one_chip,
+                                                      no_persistent_cache):
+    """The exhaustive route's ``pq_topk`` for one v5e: the kernel's custom
+    call is still the instruction ``%pq_topk.<n>`` (the name a device
+    trace finds it by) inside the ``head.*`` scopes, which tag its ops."""
+    import re
+
+    from repro.kernels.pqtopk import ops
+    _, n, b, code_dtype = width
+    codes = jax.ShapeDtypeStruct((n, 8), code_dtype, sharding=one_chip)
+    s = jax.ShapeDtypeStruct((16, 8, b), jnp.float32, sharding=one_chip)
+    text = jax.jit(lambda c, s: ops.pq_topk(c, s, 10, interpret=False)
+                   ).lower(codes, s).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert len(calls) == 1
+    assert re.match(r"\s*%pq_topk(\.\d+)? = .*custom-call\(", calls[0])
+    assert "/head.kernel/" in calls[0]
+    for scope in ("head.codes_pad", "head.merge"):
+        assert f"/{scope}/" in text
