@@ -12,7 +12,8 @@ computed on the same chip.
 
 Phases (one chip):
   fused   the config's serve route (``pqtopk_fused``, the Pallas
-          score + top-k kernel over every item tile).
+          score + top-k kernel over every item tile); also prints the
+          kernel's extraction share at the benchmark cells' shapes.
   pruned  ``pqtopk_pruned``: bounds, theta, compaction into a -1-padded
           tile table and the same kernel over the surviving tiles, under
           the calibrated slot-budget ladder.
@@ -46,6 +47,8 @@ ARCH = "sasrec-recjpq"
 N_REQUESTS = 64
 BUCKETS = (1, 64)
 K = 10
+#: (users, k bucket) of the benchmark's steady and candgen cells.
+SHARE_SHAPES = ((64, 16), (256, 128))
 
 
 def make_histories(cfg, n: int, seed: int):
@@ -118,6 +121,27 @@ def sentinel_table_check(params, cfg, hists, k: int):
     return len(table), len(table) - len(kept), bad
 
 
+def extraction_shares(params, cfg, seed: int):
+    """The fused kernel's extraction share (max-extract passes run over
+    slots x batch tiles x K, ``ops.extraction_share``) at the benchmark
+    cells' shapes: 64 users at k bucket 16 (online top-10) and 256 users
+    at k bucket 128 (top-100 candidate generation). -> {shape: share}."""
+    import jax
+    from repro.core import scoring
+    from repro.kernels.pqtopk import ops
+    from repro.models import seqrec
+    head = params["item_emb"]
+    phi_fn = jax.jit(seqrec.sequence_embedding, static_argnums=2)
+    shares = {}
+    for bq, k in SHARE_SHAPES:
+        phi = phi_fn(params, pad_histories(
+            cfg, make_histories(cfg, bq, seed + bq)), cfg)
+        s = scoring.subid_scores(head["sub_emb"], phi)
+        shares[f"b{bq}_k{k}"] = float(ops.extraction_share(head["codes"], s,
+                                                           k))
+    return shares
+
+
 def count_mismatches(results, ref_ids, ref_scores) -> int:
     """Requests whose ids differ from the reference or whose scores are
     not within f32 tolerance of it."""
@@ -183,7 +207,7 @@ def check_engine(engine, hists, ref_ids, ref_scores, *, k: int,
 
 
 def route_phase(params, cfg, method: str, hists, *, k: int = K,
-                expect_kernel: bool = True):
+                expect_kernel: bool = True, seed: int = 0):
     """One-chip phase: the engine on ``method`` against the XLA route."""
     import jax
     from repro.serving.engine import RetrievalEngine
@@ -195,6 +219,8 @@ def route_phase(params, cfg, method: str, hists, *, k: int = K,
     rep = {"phase": method, "build_s": build_s,
            **check_engine(engine, hists, ref_ids, ref_scores, k=k,
                           expect_kernel=expect_kernel)}
+    if method == "pqtopk_fused":
+        rep["extraction_share"] = extraction_shares(params, cfg, seed)
     if method == "pqtopk_pruned":
         slots, sentinels, bad = sentinel_table_check(params, cfg, hists, k)
         rep.update(table_slots=slots, sentinel_slots=sentinels,
@@ -280,7 +306,8 @@ def main(argv=None) -> int:
     if args.four_chips:
         phases = [lambda: sharded_phase(params, cfg, hists, devices[:4])]
     else:
-        phases = [lambda m=m: route_phase(params, cfg, m, hists)
+        phases = [lambda m=m: route_phase(params, cfg, m, hists,
+                                          seed=args.seed)
                   for m in (cfg.serve_method, "pqtopk_pruned")]
     ok = True
     for phase in phases:

@@ -11,6 +11,7 @@ serves a release that is not installed.
 * ``axis_size``    — ``jax.lax.axis_size``.
 * ``ANY`` / ``VMEM`` / ``SMEM`` — Pallas TPU memory spaces.
 * ``prefetch_scalar_grid_spec`` — ``pltpu.PrefetchScalarGridSpec``.
+* ``tpu_params``   — ``pltpu.CompilerParams``.
 * ``on_tpu()``     — backend probe shared by the kernel wrappers to pick
   interpret mode on CPU hosts.
 """
@@ -42,13 +43,22 @@ SMEM = _pltpu.MemorySpace.SMEM
 
 
 def prefetch_scalar_grid_spec(*, num_scalar_prefetch, grid, in_specs,
-                              out_specs):
+                              out_specs, scratch_shapes=()):
     """``pltpu.PrefetchScalarGridSpec`` — the TPU grid spec whose scalar
     operands are available to BlockSpec index maps (the mechanism behind
-    the compacted tile-index grid of the pruned retrieval route)."""
+    the compacted tile-index grid of the pruned retrieval route).
+    ``scratch_shapes`` (e.g. ``VMEM(shape, dtype)``) persist across grid
+    steps."""
     return _pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_scalar_prefetch, grid=grid,
-        in_specs=in_specs, out_specs=out_specs)
+        in_specs=in_specs, out_specs=out_specs,
+        scratch_shapes=scratch_shapes)
+
+
+def tpu_params(*, dimension_semantics):
+    """``pltpu.CompilerParams``: ``"parallel"`` or ``"arbitrary"`` (visited
+    in order on one core) per grid dimension."""
+    return _pltpu.CompilerParams(dimension_semantics=dimension_semantics)
 
 
 def on_tpu() -> bool:

@@ -13,9 +13,24 @@ Two kernels:
 
   1. **Batch tiling** — grid is (tile slot, batch tile), so B is unbounded:
      each step sees a (TB, m, b) slice of S instead of the whole batch.
-  2. **In-VMEM top-k** — K max-extract passes over the (TB, TN) score tile
-     (``_tile_topk``), built only from operations Mosaic lowers, with the
-     value-then-lowest-index order of ``lax.top_k``.
+  2. **In-VMEM top-k under a carried threshold** — max-extract passes
+     over the (TB, TN) score tile (``_tile_topk``), built only from
+     operations Mosaic lowers, with the value-then-lowest-index order of
+     ``lax.top_k``.  Each row's running top-K order keys from the tiles
+     already scored stay in a (B, K) int32 VMEM scratch, carried across
+     the slot axis (both grid axes are ``"arbitrary"``: one core visits a
+     batch tile's slots in order); theta is the row's lowest key.  A tile
+     masks every score whose key is <= theta, counts the survivors per
+     row, and runs ``min(K, max over rows of the count)`` passes, so once
+     a few tiles are seen most tiles run none or one.  Exact, ties
+     included: slots visit tiles in ascending order on every route
+     (identity table, compacted lists, each row of the grouped table), so
+     the K items behind theta all have lower global ids than any item of
+     the tile, and a score <= theta ranks after all K of them.  The
+     survivors come out in the order they always did; lanes past a row's
+     survivors read -inf with the sentinel id ``n_items``, like sentinel
+     slots.  The scratch is 4*B*K bytes, at most :data:`MAX_RUN_KEYS`
+     keys (2 MiB); a larger batch runs as several calls.
   3. **Compacted tile indices** — the item-tile axis is indirected through a
      scalar-prefetched index array (``PrefetchScalarGridSpec``), so the
      pruned retrieval route can run the same kernel over only the tiles
@@ -64,6 +79,14 @@ from repro.core.scoring import tree_sum_lazy
 DEFAULT_TILE = 2048
 DEFAULT_BATCH_TILE = 128
 NEG_INF = float("-inf")
+#: The order key below every score's (``_order_key(-inf)`` is above it):
+#: a running top-K slot that holds no item yet, or a masked score.
+NO_KEY = -2 ** 31
+#: Most (B_pad x K) running keys the carried threshold may hold: 2 MiB of
+#: int32 VMEM scratch.  With the tile working set (~12.2 MiB at TB=128,
+#: b=512) it stays inside the 16 MiB of scoped VMEM a v5e kernel gets by
+#: default (B=4096 at K=128 compiles; 4 MiB of keys does not).
+MAX_RUN_KEYS = 2 ** 19
 
 
 def _bf16_split(x):
@@ -122,40 +145,62 @@ def _order_key(x):
     return bits ^ ((bits >> 31) & 0x7FFFFFFF)
 
 
-def _tile_topk(scores, k: int):
-    """Exact top-k of one VMEM-resident (TB, TN) score tile, k max-extract
-    passes in (value descending, column ascending) order — what
-    ``lax.top_k`` returns, ties included.
+def _tile_topk(scores, k: int, run):
+    """Exact top-k of one VMEM-resident (TB, TN) score tile that can still
+    enter each row's running top-k, in (value descending, column
+    ascending) order — what ``lax.top_k`` returns, ties included.
 
-    Pass j keeps only what ranks after pass j-1's pick (a lower key, or
-    the same key at a higher column), takes the row max, and the lowest
-    column holding it.  Nothing is overwritten, so ``-inf`` padding ties
-    come out in ascending column order exactly like real ties.  Built from
-    compares, selects and lane max/min reductions: operations Mosaic
-    lowers (``lax.top_k``, ``take_along_axis`` and 3-D lane reshapes are
-    not).  Requires k <= TN.
+    ``run`` (TB, K) int32 holds each row's running top-K order keys from
+    the tiles already scored (unsorted; :data:`NO_KEY` where none yet);
+    theta is its row minimum.  A score whose key is <= theta is masked:
+    the K items behind theta all sit in earlier tiles, at lower global
+    ids, so such a score ranks after all K of them and cannot be in the
+    answer.  The loop runs ``min(K, max over rows of the survivors)``
+    passes, a trip count read from the tile itself.  Pass j keeps only
+    what ranks after pass j-1's pick (a lower key, or the same key at a
+    higher column), takes the row max, and the lowest column holding it;
+    a pick above the row's running minimum replaces it (the lowest lane
+    holding it).  Lanes past a row's survivors are not found: they read
+    ``-inf``, with ``found`` false and no column.  So the survivors are a prefix
+    of the tile's own order, and with ``run`` all :data:`NO_KEY` the
+    result is the tile's exact top-k (``-inf`` padding included, in
+    ascending column order like real ties).  Built from compares, selects
+    and lane max/min/sum reductions: operations Mosaic lowers
+    (``lax.top_k``, ``take_along_axis`` and 3-D lane reshapes are not).
+    Scores are never NaN.  Requires k <= TN.
+    -> (vals (TB, K) f32, cols (TB, K) i32, found (TB, K) bool, run).
     """
     tb, tn = scores.shape
+    theta = jnp.min(run, axis=1, keepdims=True)
     key = _order_key(scores)
+    key = jnp.where(key > theta, key, NO_KEY)
+    alive = jnp.sum((key > NO_KEY).astype(jnp.int32), axis=1, keepdims=True)
+    n_pass = jnp.minimum(jnp.max(alive), k)
     col = jax.lax.broadcasted_iota(jnp.int32, (tb, tn), 1)
     lane = jax.lax.broadcasted_iota(jnp.int32, (tb, k), 1)
-    lowest = jnp.iinfo(jnp.int32).min
 
     def body(j, carry):
-        keys, cols, prev_k, prev_c = carry
+        keys, cols, run, prev_k, prev_c = carry
         after = (key < prev_k) | ((key == prev_k) & (col > prev_c))
-        kj = jnp.max(jnp.where(after, key, lowest), axis=1, keepdims=True)
+        kj = jnp.max(jnp.where(after, key, NO_KEY), axis=1, keepdims=True)
         cj = jnp.min(jnp.where(after & (key == kj), col, tn), axis=1,
                      keepdims=True)
+        low = jnp.min(run, axis=1, keepdims=True)
+        at = jnp.min(jnp.where(run == low, lane, k), axis=1, keepdims=True)
+        run = jnp.where((lane == at) & (kj > low), kj, run)
         return (jnp.where(lane == j, kj, keys), jnp.where(lane == j, cj, cols),
-                kj, cj)
+                run, kj, cj)
 
-    init = (jnp.zeros((tb, k), jnp.int32), jnp.zeros((tb, k), jnp.int32),
+    init = (jnp.full((tb, k), NO_KEY, jnp.int32),
+            jnp.zeros((tb, k), jnp.int32), run,
             jnp.full((tb, 1), jnp.iinfo(jnp.int32).max, jnp.int32),
             jnp.full((tb, 1), -1, jnp.int32))
-    keys, cols, _, _ = jax.lax.fori_loop(0, k, body, init)
-    vals = jax.lax.bitcast_convert_type(_order_key(keys), jnp.float32)
-    return vals, cols
+    keys, cols, run, _, _ = jax.lax.fori_loop(0, n_pass, body, init)
+    found = keys > NO_KEY
+    vals = jnp.where(found, jax.lax.bitcast_convert_type(_order_key(keys),
+                                                         jnp.float32),
+                     NEG_INF)
+    return vals, cols, found, run
 
 
 def pq_topk_fused_kernel(idx_ref, codes_ref, s_ref, *rest,
@@ -166,15 +211,25 @@ def pq_topk_fused_kernel(idx_ref, codes_ref, s_ref, *rest,
         # rides along each codes tile under the SAME clamped index map, so
         # delisted items are masked to -inf inside the tile top-k — before
         # they can crowd a live winner out of the per-tile candidate set.
-        live_ref, out_v_ref, out_i_ref = rest
+        live_ref, out_v_ref, out_i_ref, run_ref = rest
     else:
-        out_v_ref, out_i_ref = rest
+        out_v_ref, out_i_ref, run_ref = rest
     if len(idx_ref.shape) == 2:
         # Grouped route: grid (n_batch_tiles, n_slots) — batch tile j's
         # slot i reads its own row of the 2D (group, slot) table.
-        tile_id = idx_ref[pl.program_id(0), pl.program_id(1)]
+        bt, slot = pl.program_id(0), pl.program_id(1)
+        tile_id = idx_ref[bt, slot]
     else:
-        tile_id = idx_ref[pl.program_id(0)]
+        slot, bt = pl.program_id(0), pl.program_id(1)
+        tile_id = idx_ref[slot]
+    tb = out_v_ref.shape[0]
+    rows = pl.ds(pl.multiple_of(bt * tb, tb), tb)
+
+    # A batch tile's running top-K keys start empty at its first slot and
+    # are carried through its slots in order (the grid is sequential).
+    @pl.when(slot == 0)
+    def _start():
+        run_ref[rows, :] = jnp.full((tb, k), NO_KEY, jnp.int32)
 
     # Sentinel slots (tile_id == -1): the in-graph pruned route's slot-
     # buffer padding.  Early-exit — no scoring, no top-k; and because the
@@ -191,7 +246,7 @@ def pq_topk_fused_kernel(idx_ref, codes_ref, s_ref, *rest,
     @pl.when(tile_id >= 0)
     def _score():
         scores = _tile_scores(codes_ref, s_ref)       # (TB, TN)
-        tb, tn = scores.shape
+        tn = scores.shape[1]
         col = jax.lax.broadcasted_iota(jnp.int32, (tb, tn), 1)
         # Mask padding beyond the true catalogue size; legacy past-catalogue
         # sentinel tiles land entirely here.
@@ -200,9 +255,10 @@ def pq_topk_fused_kernel(idx_ref, codes_ref, s_ref, *rest,
         if has_live:
             ok = ok & (live_ref[...] != 0)            # (1, TN) broadcast
         scores = jnp.where(ok, scores, NEG_INF)
-        vals, cols = _tile_topk(scores, k)
+        vals, cols, found, run = _tile_topk(scores, k, run_ref[rows, :])
+        run_ref[rows, :] = run
         out_v_ref[...] = vals
-        out_i_ref[...] = cols + tile_id * tile
+        out_i_ref[...] = jnp.where(found, cols + tile_id * tile, n_items)
 
 
 def pq_scores_call(codes: jax.Array, s: jax.Array, *, tile: int = DEFAULT_TILE,
@@ -257,12 +313,25 @@ def pq_topk_fused_call(codes: jax.Array, s: jax.Array, k: int, *,
     the tile top-k; the sentinel-clamp contract (``-1`` -> block 0) is
     unchanged.  Extra HBM traffic: 1 byte/item — noise next to the m
     bytes/item of codes.
+
+    Each row's running top-K keys live in a (B, K) int32 VMEM scratch, so
+    a batch of more than :data:`MAX_RUN_KEYS` / K rows runs as several
+    calls of at most that many rows each.
     """
     n, m = codes.shape
     bq, m2, b = s.shape
     assert m == m2 and n % tile == 0
     assert bq % batch_tile == 0, (bq, batch_tile)
     assert k <= tile, (k, tile)
+    rows = max(MAX_RUN_KEYS // k // batch_tile, 1) * batch_tile
+    if bq > rows:
+        parts = [pq_topk_fused_call(
+            codes, s[a:a + rows], k, n_items=n_items, tile=tile,
+            batch_tile=batch_tile, live=live, interpret=interpret,
+            tile_idx=(tile_idx[a // batch_tile:(a + rows) // batch_tile]
+                      if tile_idx.ndim == 2 else tile_idx))
+            for a in range(0, bq, rows)]
+        return [jnp.concatenate(p, axis=1) for p in zip(*parts)]
     n_bt = bq // batch_tile
     if live is not None:
         assert live.shape == (n // tile, 1, tile), (live.shape, n, tile)
@@ -306,6 +375,7 @@ def pq_topk_fused_call(codes: jax.Array, s: jax.Array, k: int, *,
         grid=grid,
         in_specs=in_specs,
         out_specs=[out_spec, out_spec],
+        scratch_shapes=[compat.VMEM((bq, k), jnp.int32)],
     )
     return pl.pallas_call(
         kern,
@@ -314,5 +384,8 @@ def pq_topk_fused_call(codes: jax.Array, s: jax.Array, k: int, *,
             jax.ShapeDtypeStruct((n_slots, bq, k), jnp.float32),
             jax.ShapeDtypeStruct((n_slots, bq, k), jnp.int32),
         ],
+        # Sequential on one core: the running keys carry across slots.
+        compiler_params=compat.tpu_params(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(tile_idx.astype(jnp.int32), *operands)

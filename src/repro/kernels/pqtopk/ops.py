@@ -151,6 +151,36 @@ def pq_topk(codes: jax.Array, s: jax.Array, k: int, *,
         return _merge_slot_winners(tv, ti, k, bq)
 
 
+@functools.partial(jax.jit, static_argnames=("k", "tile", "batch_tile",
+                                             "interpret"))
+def extraction_share(codes: jax.Array, s: jax.Array, k: int, *,
+                     tile: int = _k.DEFAULT_TILE,
+                     batch_tile: int = _k.DEFAULT_BATCH_TILE,
+                     interpret: bool | None = None) -> jax.Array:
+    """How often the fused kernel's carried threshold lets it skip work on
+    the exhaustive route of :func:`pq_topk`: max-extract passes run over
+    (slots x batch tiles x K).  A (slot, batch tile) block ran as many
+    passes as its row with the most finite candidates, read from the
+    kernel's own outputs (``-inf`` candidates, padding or tombstones, do
+    not count).  1 when every tile needs all K passes (scores rising
+    tile by tile).  Runs the kernel again, so the served step never calls
+    it. -> f32 scalar."""
+    if interpret is None:
+        interpret = not compat.on_tpu()
+    n = codes.shape[0]
+    tile = min(tile, _round_up(n, 128))
+    bt = effective_batch_tile(s.shape[0], batch_tile)
+    padded = _pad_codes(codes, tile)
+    s = _pad_batch(s, bt)
+    idx = jnp.arange(padded.shape[0] // tile, dtype=jnp.int32)
+    tv, _ = _k.pq_topk_fused_call(padded, s, k, tile_idx=idx, n_items=n,
+                                  tile=tile, batch_tile=bt,
+                                  interpret=interpret)
+    found = (tv > NEG_INF).sum(axis=-1)                 # (n_slots, B_pad)
+    passes = found.reshape(found.shape[0], -1, bt).max(axis=-1)
+    return passes.sum() / (passes.size * k)
+
+
 def _pq_topk_tiles(codes: jax.Array, s: jax.Array, k: int,
                    tile_idx: jax.Array, *, tile: int, batch_tile: int,
                    use_kernel: bool, interpret: bool, live=None):

@@ -48,6 +48,10 @@ def test_route_phase_matches_xla_reference(smoke, model, method):
     _passed(rep)
     assert rep["n_compiles"] == len(smoke.BUCKETS)
     assert ("ladder" in rep) == (method == "pqtopk_pruned")
+    if method == "pqtopk_fused":
+        shares = rep["extraction_share"]
+        assert sorted(shares) == ["b256_k128", "b64_k16"]
+        assert all(0.0 < v <= 1.0 for v in shares.values()), shares
     if method == "pqtopk_pruned":
         assert rep["sentinel_slots"] > 0
         assert rep["sentinel_table_mismatches"] == 0

@@ -197,3 +197,115 @@ def test_bf16_split_rebuilds_f32_exactly():
     back = (hi.astype(jnp.float32) + mid.astype(jnp.float32)) \
         + lo.astype(jnp.float32)
     np.testing.assert_array_equal(np.asarray(back), x)
+
+
+# ---------------------------------------------------------------------------
+# the carried threshold: each row's running top-K keys cut a tile's
+# max-extract passes; answers stay bit-identical to the pqtopk route
+# ---------------------------------------------------------------------------
+
+def _threshold_case(name):
+    """-> (codes, s, k, tile, batch_tile, table, live, share): ``table``
+    None for the exhaustive route, else a 1-D or 2-D tile table for
+    ``pq_topk_tiles``; ``share`` the extraction share the exhaustive route
+    must read, or None."""
+    rng = np.random.default_rng(len(name))
+    tile, n_tiles, m, b, bq, k = 128, 12, 2, 64, 3, 8
+    n = tile * n_tiles
+    codes = rng.integers(0, b, (n, m))
+    s = table = live = share = None
+    if name in ("rising", "falling"):
+        # Split 0 alone scores: tile t's items take codes 4t..4t+3, so
+        # every tile beats (rising) or loses to (falling) all before it.
+        b = 4 * n_tiles
+        codes[:, 0] = np.arange(n) // tile * 4 + rng.integers(0, 4, n)
+        codes[:, 1] = 0
+        s = np.zeros((bq, m, b))
+        s[:, 0] = np.arange(b) * (1.0 if name == "rising" else -1.0)
+        share = 1.0 if name == "rising" else 1.0 / n_tiles
+    elif name == "ties":
+        codes[:] = 0
+        share = 1.0 / n_tiles
+    elif name == "k_is_tile":
+        k = tile
+    elif name == "uint16_b512_k128":
+        b, k, tile = 512, 128, 256
+        codes = rng.integers(0, b, (n, m))
+    elif name == "padded_last_tile":
+        n = tile * (n_tiles - 1) + 3
+        codes = codes[:n]
+    elif name == "sentinels":
+        table = np.full(n_tiles, -1, np.int32)
+        table[:n_tiles // 2] = np.arange(0, n_tiles, 2)
+    elif name == "grouped":
+        bq = 16
+        table = np.full((2, n_tiles), -1, np.int32)
+        table[0, :5] = [0, 2, 3, 7, 11]
+        table[1, :8] = [1, 2, 4, 5, 6, 8, 9, 10]
+    elif name == "live":
+        table = np.arange(n_tiles, dtype=np.int32)
+        live = rng.random(n) < 0.3
+    if s is None:
+        s = rng.standard_normal((bq, m, b))
+    dtype = jnp.uint16 if b > 256 else jnp.int8
+    return (jnp.asarray(codes, dtype), jnp.asarray(s, jnp.float32), k, tile,
+            8, table, live, share)
+
+
+@pytest.mark.parametrize("name", [
+    "rising", "falling", "ties", "k_is_tile", "uint16_b512_k128",
+    "padded_last_tile", "sentinels", "grouped", "live"])
+def test_carried_threshold_matches_pqtopk(name):
+    """The fused kernel, whose tiles run only the passes each row's
+    running threshold leaves, returns what the pqtopk route returns, bit
+    for bit in values and ids, over many tiles: scores rising tile by
+    tile (no pruning), falling (none after the first tile), all tied,
+    k = tile, b=512 uint16 codes at k=128, a last tile mostly padding, a
+    compacted table with -1 sentinels, a 2-D grouped table and a
+    tombstone mask."""
+    codes, s, k, tile, bt, table, live, share = _threshold_case(name)
+    if table is None:
+        got = pq_ops.pq_topk(codes, s, k, tile=tile, batch_tile=bt,
+                             interpret=True)
+        r = scoring.score_pqtopk(codes.astype(jnp.int32), s)
+        want = jax.lax.top_k(r, k)
+        got_share = float(pq_ops.extraction_share(
+            codes, s, k, tile=tile, batch_tile=bt, interpret=True))
+        if share is None:
+            assert 0.0 < got_share <= 1.0
+        else:
+            assert got_share == pytest.approx(share, abs=1e-7)
+    else:
+        table = jnp.asarray(table)
+        live = None if live is None else jnp.asarray(live)
+        got, want = (pq_ops.pq_topk_tiles(codes, s, k, table, tile=tile,
+                                          batch_tile=bt, live=live,
+                                          use_kernel=uk, interpret=True)
+                     for uk in (True, False))
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+
+
+@pytest.mark.parametrize("grouped", [False, True], ids=["1d", "2d"])
+def test_batch_past_the_running_key_bound_runs_in_pieces(grouped,
+                                                         monkeypatch):
+    """A batch with more rows than ``MAX_RUN_KEYS`` / K runs as several
+    kernel calls, each with its own running keys (and, on the grouped
+    route, its own rows of the table); the answers do not change."""
+    from repro.kernels.pqtopk import kernel as pq_kernel
+    monkeypatch.setattr(pq_kernel, "MAX_RUN_KEYS", 16 * 8)  # 16 rows at k=8
+    rng = np.random.default_rng(3)
+    n, tile, k, bq = 128 * 6, 128, 8, 40
+    codes = jnp.asarray(rng.integers(0, 32, (n, 2)), jnp.int8)
+    s = jnp.asarray(rng.standard_normal((bq, 2, 32)), jnp.float32)
+    table = np.arange(6, dtype=np.int32)
+    if grouped:                 # batch tile r scores tiles t % (r+2) == 0
+        rows = [[t for t in range(6) if t % (r + 2) == 0] for r in range(5)]
+        table = np.array([row + [-1] * (6 - len(row)) for row in rows],
+                         np.int32)
+    got, want = (pq_ops._pq_topk_tiles(codes, s, k, jnp.asarray(table),
+                                       tile=tile, batch_tile=8,
+                                       use_kernel=uk, interpret=True)
+                 for uk in (True, False))
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))

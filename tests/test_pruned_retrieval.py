@@ -176,18 +176,25 @@ def test_tile_topk_matches_lax_top_k(k, layout):
     """The kernel's in-VMEM max-extract top-k returns exactly what
     ``lax.top_k`` returns — values, and columns in value-then-lowest-
     column order — including heavy ties and a ``-inf`` masked tail, where
-    padding columns must come out in ascending order, never repeated."""
-    from repro.kernels.pqtopk.kernel import _tile_topk
+    padding columns must come out in ascending order, never repeated.
+    With no running keys yet (the tile's first slot) every lane is found,
+    and the running keys come back as the picks' keys."""
+    from repro.kernels.pqtopk.kernel import NO_KEY, _order_key, _tile_topk
     rng = np.random.default_rng(k)
     scores = rng.normal(size=(8, 128)).astype(np.float32)
     if layout == "ties":
         scores = np.round(scores * 2) / 2           # few distinct values
     elif layout == "neg_inf_tail":
         scores[:, 5:] = -np.inf
-    v, c = jax.jit(_tile_topk, static_argnums=1)(jnp.asarray(scores), k)
+    run = jnp.full((8, k), NO_KEY, jnp.int32)
+    v, c, found, run = jax.jit(_tile_topk, static_argnums=1)(
+        jnp.asarray(scores), k, run)
     v_ref, c_ref = jax.lax.top_k(jnp.asarray(scores), k)
     np.testing.assert_array_equal(np.asarray(v), np.asarray(v_ref))
     np.testing.assert_array_equal(np.asarray(c), np.asarray(c_ref))
+    assert np.asarray(found).all()
+    np.testing.assert_array_equal(np.sort(np.asarray(run), axis=1),
+                                  np.sort(np.asarray(_order_key(v_ref)), 1))
 
 
 def test_pq_topk_tiles_sentinel_padding():

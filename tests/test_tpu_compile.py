@@ -95,6 +95,32 @@ def test_fused_kernel_compiles_for_v5e(width, table, batch_tile, one_chip,
     assert out_v.shape == out_i.shape == (n_slots, bq, K)
 
 
+@pytest.mark.parametrize("bq", [256, pq_kernel.MAX_RUN_KEYS // 128],
+                         ids=["candgen", "run_keys_bound"])
+def test_fused_kernel_compiles_with_k128_running_keys(bq, one_chip,
+                                                      no_persistent_cache):
+    """At k=128 and the Gowalla width the (B, K) running-key scratch fits
+    the chip's scoped VMEM beside the tile working set: at the candidate-
+    generation batch (B=256) and at the largest batch one call takes
+    (``MAX_RUN_KEYS`` keys)."""
+    _, n, b, code_dtype = WIDTHS[0]
+    n_rows = -(-n // TILE) * TILE
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fused(codes, s, tile_idx):
+        return pq_kernel.pq_topk_fused_call(
+            codes, s, 128, tile_idx=tile_idx, n_items=n, tile=TILE,
+            batch_tile=128)
+
+    compiled = jax.jit(fused).lower(
+        sds((n_rows, 8), code_dtype), sds((bq, 8, b), jnp.float32),
+        sds((n_rows // TILE,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+
+
 @pytest.mark.parametrize("width", WIDTHS, ids=[w[0] for w in WIDTHS])
 def test_kernel_call_keeps_its_name_inside_the_scopes(width, one_chip,
                                                       no_persistent_cache):
