@@ -11,11 +11,13 @@ from typing import Any, Dict
 def real_positions(cfg: Dict[str, Any], history_len: int) -> int:
     """Positions of a request that carry its history: the last
     ``max_seq_len`` items, and for BERT4Rec the [MASK] slot that replaces
-    the oldest one."""
+    the oldest one.  Another backbone brings its own count."""
     s = cfg["max_seq_len"]
     if cfg["backbone"] == "bert4rec":
         return min(history_len, s - 1) + 1
-    return min(history_len, s)
+    if cfg["backbone"] == "sasrec":
+        return min(history_len, s)
+    raise ValueError(f"no count of real positions for {cfg['backbone']!r}")
 
 
 def backbone_flops(cfg: Dict[str, Any], positions: int) -> float:
@@ -23,9 +25,15 @@ def backbone_flops(cfg: Dict[str, Any], positions: int) -> float:
     as if each of them is computed (padding is not useful work): per block
     the q, k, v and output projections, the attention scores and their
     weighted sum (causal pairs for SASRec, all pairs for BERT4Rec), and the
-    two MLP matrices.  Norms, softmax and activations are left out."""
+    two MLP matrices.  Norms, softmax and activations are left out.
+    Another backbone brings its own count."""
     d, ff, n = cfg["d_model"], cfg["d_ff"], positions
-    pairs = n * (n + 1) / 2 if cfg["backbone"] == "sasrec" else n * n
+    if cfg["backbone"] == "sasrec":
+        pairs = n * (n + 1) / 2
+    elif cfg["backbone"] == "bert4rec":
+        pairs = n * n
+    else:
+        raise ValueError(f"no FLOP count for backbone {cfg['backbone']!r}")
     per_block = 2 * n * d * 4 * d + 2 * 2 * pairs * d + 2 * n * 2 * d * ff
     return float(cfg["n_blocks"] * per_block)
 

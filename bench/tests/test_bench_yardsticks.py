@@ -43,6 +43,13 @@ def test_backbone_and_head_flops_by_hand():
                                               + costs.head_flops(SASREC))
 
 
+@pytest.mark.parametrize("count", [costs.backbone_flops,
+                                   costs.real_positions])
+def test_unknown_backbone_has_no_count(count):
+    with pytest.raises(ValueError, match="lfm2"):
+        count({**SASREC, "backbone": "lfm2"}, 3)
+
+
 def test_padding_positions_are_not_useful_work():
     assert costs.request_flops(SASREC, 2) < costs.request_flops(SASREC, 5)
     assert costs.request_flops(SASREC, 5) == costs.request_flops(SASREC, 500)
